@@ -1,7 +1,7 @@
-"""Real-mesh runtime benchmarks (DESIGN.md §11) on forced host devices.
+"""Real-mesh runtime benchmarks (DESIGN.md §11).
 
-Measures, on an 8-way host-device mesh (true multi-device SPMD on CPU —
-the same GSPMD partitioning a TPU pod would run, minus the interconnect):
+Measures, on a 1D mesh over every device of the backend (real chips, or
+forced host devices on CPU — the same GSPMD partitioning either way):
 
   - decode step time: replicated single-device engine vs the same tiny
     config mesh-sharded through `sharding_context` (the absolute numbers
@@ -16,27 +16,23 @@ the same GSPMD partitioning a TPU pod would run, minus the interconnect):
     (`execute_weight_update`): measured per-chunk t_exec_s, the runtime
     companion of the dry-run's compiled t_collective_s estimate
 
-Emits ``BENCH_mesh.json``. When the current process has fewer than 8
-devices (XLA fixes the device count at backend init), the group respawns
-itself in a subprocess with
-``XLA_FLAGS=--xla_force_host_platform_device_count=8`` and relays the
-rows.
+Emits ``BENCH_mesh.json``. The group runs in this process on every device
+the backend has (one process per chip: a parent that has touched JAX holds
+the devices). On CPU, force the device count before JAX starts:
 
-    PYTHONPATH=src python -m benchmarks.run --only mesh
+    XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+        PYTHONPATH=src python -m benchmarks.run --only mesh
 """
 from __future__ import annotations
 
 import json
 import os
-import subprocess
-import sys
 import time
 from typing import List, Tuple
 
 Row = Tuple[str, float, str]
 
 JSON_PATH = "BENCH_mesh.json"
-N_DEV = 8
 N_CHUNKS = 4
 
 
@@ -59,8 +55,9 @@ def _step_time(engine, task, iters=15):
     return _median(times)
 
 
-def _run() -> List[Row]:
+def mesh_benchmarks() -> List[Row]:
     import jax
+    from jax.sharding import AxisType
 
     from repro.configs.tiny import config as tiny_config
     from repro.core.events import chunk_spans, chunk_token, span_bytes, \
@@ -72,7 +69,12 @@ def _run() -> List[Row]:
     from repro.models import model as M
     from repro.sharding import tree_values
 
-    mesh = jax.make_mesh((N_DEV,), ("model",))
+    n_dev = jax.device_count()
+    if n_dev < 2:
+        raise RuntimeError(
+            "the mesh group needs several devices; on CPU set XLA_FLAGS="
+            "--xla_force_host_platform_device_count=8 before starting")
+    mesh = jax.make_mesh((n_dev,), ("model",), (AxisType.Auto,))
     backend = jax.default_backend()
     # identically-seeded tasks give each engine the same prompt sequence
     task_a = MathTask(max_operand=5, ops="+")
@@ -121,7 +123,7 @@ def _run() -> List[Row]:
         ("mesh/decode_step_replicated", t_rep * 1e6,
          f"backend={backend};n_dev=1"),
         ("mesh/decode_step_sharded", t_shard * 1e6,
-         f"backend={backend};n_dev={N_DEV};"
+         f"backend={backend};n_dev={n_dev};"
          f"sharded/replicated={t_shard / max(t_rep, 1e-12):.2f}x"),
         ("mesh/broadcast_chunk_install", _median(chunk_s) * 1e6,
          f"n_chunks={N_CHUNKS};max_us={max(chunk_s) * 1e6:.1f};"
@@ -142,7 +144,7 @@ def _run() -> List[Row]:
     ]
 
     payload = {
-        "config": {"n_dev": N_DEV, "n_chunks": N_CHUNKS, "backend": backend,
+        "config": {"n_dev": n_dev, "n_chunks": N_CHUNKS, "backend": backend,
                    "d_model": 64, "n_layers": 1},
         "decode_step_s": {"replicated": t_rep, "sharded": t_shard},
         "broadcast": {"chunk_s": chunk_s, "atomic_s": atomic_s,
@@ -162,33 +164,6 @@ def _run() -> List[Row]:
     with open(JSON_PATH, "w") as f:
         json.dump(payload, f, indent=2)
     rows.append(("mesh/json", 0.0, os.path.abspath(JSON_PATH)))
-    return rows
-
-
-def mesh_benchmarks() -> List[Row]:
-    import jax
-    if jax.device_count() >= N_DEV:
-        return _run()
-    # XLA fixes the device count when the backend initializes; respawn
-    # with forced host devices and relay the rows
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                        + f" --xla_force_host_platform_device_count={N_DEV}"
-                        ).strip()
-    env["PYTHONPATH"] = (os.path.join(root, "src") + os.pathsep
-                         + env.get("PYTHONPATH", ""))
-    proc = subprocess.run([sys.executable, "-m", "benchmarks.mesh_bench"],
-                          env=env, cwd=root, capture_output=True, text=True,
-                          timeout=1800)
-    if proc.returncode != 0:
-        raise RuntimeError("mesh bench subprocess failed:\n"
-                           + proc.stdout[-1000:] + proc.stderr[-2000:])
-    rows: List[Row] = []
-    for line in proc.stdout.splitlines():
-        parts = line.split(",", 2)
-        if len(parts) == 3 and parts[0].startswith("mesh/"):
-            rows.append((parts[0], float(parts[1]), parts[2]))
     return rows
 
 

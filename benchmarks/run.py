@@ -25,6 +25,9 @@ def main() -> None:
     if args.full:
         os.environ["BENCH_FAST"] = "0"
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     # imports after BENCH_FAST is settled
     from benchmarks import figures
     from benchmarks.engine_bench import engine_benchmarks

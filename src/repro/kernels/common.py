@@ -2,10 +2,6 @@
 from __future__ import annotations
 
 import jax
-import jax.experimental.pallas.tpu as pltpu
-
-# MemorySpace was named TPUMemorySpace before jax 0.5
-MEMSPACE = getattr(pltpu, "MemorySpace", None) or pltpu.TPUMemorySpace
 
 
 def default_interpret(interpret: bool | None) -> bool:

@@ -14,8 +14,9 @@ The kernel is *length-aware* at two levels:
   `max(lengths)` over the batch, rounded up to `block_k`) shrinks the
   trailing grid axis itself, so blocks beyond the hint are never fetched
   from HBM at all (the `pl.when` variant still paid the DMA);
-- **block-level** — the per-slot valid length lives in SMEM and KV blocks
-  entirely beyond it skip the QK^T / PV dots via `pl.when` — in a
+- **block-level** — the per-slot valid lengths ride in SMEM as a
+  scalar-prefetch operand and KV blocks entirely beyond a slot's length
+  skip the QK^T / PV dots via `pl.when` — in a
   continuous-batching engine most slots are far from the cache capacity,
   so the common case touches only `ceil(len/block_k)` blocks' worth of
   MXU work instead of `CL/block_k`.
@@ -33,14 +34,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels.common import MEMSPACE as _MEMSPACE, default_interpret
+from repro.kernels.common import default_interpret
 
 NEG_INF = -1e30
 
 
 def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
                    *, scale: float, block_k: int, n_kv_blocks: int):
-    ki = pl.program_id(2)
+    b, ki = pl.program_id(0), pl.program_id(2)
+    n_valid = len_ref[b]
 
     @pl.when(ki == 0)
     def _init():
@@ -50,7 +52,7 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
 
     # length-aware skip: blocks whose first slot is already past this
     # sequence's valid length contribute nothing — don't issue the dots
-    @pl.when(ki * block_k < len_ref[0])
+    @pl.when(ki * block_k < n_valid)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)                 # (rep, d)
         k = k_ref[0, 0].astype(jnp.float32)                 # (bk, d)
@@ -58,7 +60,7 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         valid = (ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (q.shape[0], block_k), 1)) < len_ref[0]
+            jnp.int32, (q.shape[0], block_k), 1)) < n_valid
         s = jnp.where(valid, s, NEG_INF)
         m_prev, l_prev = m_ref[...], l_ref[...]
         m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
@@ -109,23 +111,31 @@ def flash_decode(q, k_cache, v_cache, lengths, *, scale: float,
 
     kernel = functools.partial(_decode_kernel, scale=scale, block_k=block_k,
                                n_kv_blocks=nk)
-    out = pl.pallas_call(
-        kernel,
+    # lengths are a scalar-prefetch operand: the whole (B,) vector sits in
+    # SMEM for the kernel's lifetime (a per-row rank-1 SMEM block is not a
+    # legal TPU block shape)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(B, KV, nk),
         in_specs=[
-            pl.BlockSpec((1,), lambda b, h, ki: (b,),
-                         memory_space=_MEMSPACE.SMEM),
-            pl.BlockSpec((1, 1, rep, Dk), lambda b, h, ki: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, block_k, Dk), lambda b, h, ki: (b, h, ki, 0)),
-            pl.BlockSpec((1, 1, block_k, Dv), lambda b, h, ki: (b, h, ki, 0)),
+            pl.BlockSpec((1, 1, rep, Dk), lambda b, h, ki, lens: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, block_k, Dk),
+                         lambda b, h, ki, lens: (b, h, ki, 0)),
+            pl.BlockSpec((1, 1, block_k, Dv),
+                         lambda b, h, ki, lens: (b, h, ki, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, rep, Dv), lambda b, h, ki: (b, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, KV, rep, Dv), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, rep, Dv),
+                               lambda b, h, ki, lens: (b, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((rep, 1), jnp.float32),
             pltpu.VMEM((rep, 1), jnp.float32),
             pltpu.VMEM((rep, Dv), jnp.float32),
         ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, KV, rep, Dv), q.dtype),
         interpret=interpret,
     )(lengths, qr, kr, vr)
     return out.reshape(B, H, Dv)

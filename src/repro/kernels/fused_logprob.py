@@ -581,7 +581,6 @@ def fused_logprob_sharded(hidden, head, targets, *, mesh=None,
     Value and grads match the single-device path to fp32 tolerance (the
     shard cut only reassociates the vocab reduction, like a different
     block_v would)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     interpret = default_interpret(interpret)
@@ -630,7 +629,7 @@ def fused_logprob_sharded(hidden, head, targets, *, mesh=None,
         return tgt - lse, lse, ent
 
     w_spec = P(axis_name, None) if transpose_head else P(None, axis_name)
-    return shard_map(shard_fn, mesh=mesh,
-                     in_specs=(P(), w_spec, P()),
-                     out_specs=(P(), P(), P()),
-                     check_rep=False)(hidden, head, targets)
+    return jax.shard_map(shard_fn, mesh=mesh,
+                         in_specs=(P(), w_spec, P()),
+                         out_specs=(P(), P(), P()),
+                         check_vma=False)(hidden, head, targets)

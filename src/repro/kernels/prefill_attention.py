@@ -39,7 +39,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels.common import MEMSPACE as _MEMSPACE, default_interpret
+from repro.kernels.common import default_interpret
 
 NEG_INF = -1e30
 
@@ -154,7 +154,7 @@ def prefill_attention(q, k_chunk, v_chunk, k_cache, v_cache, offset, *,
         grid=(B, KV, nkb + 1),
         in_specs=[
             pl.BlockSpec((1,), lambda b, h, ki: (0,),
-                         memory_space=_MEMSPACE.SMEM),
+                         memory_space=pltpu.MemorySpace.SMEM),
             pl.BlockSpec((1, 1, rows, Dk), lambda b, h, ki: (b, h, 0, 0)),
             pl.BlockSpec((1, 1, block_k, Dk),
                          lambda b, h, ki, _n=max(nkb - 1, 0):

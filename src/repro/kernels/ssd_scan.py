@@ -18,7 +18,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels.common import MEMSPACE as _MEMSPACE, default_interpret
+from repro.kernels.common import default_interpret
 
 
 def _ssd_kernel(a_ref, x_ref, dt_ref, b_ref, c_ref, y_ref, fstate_ref,
@@ -85,7 +85,7 @@ def ssd_scan(x, dt, A, B, C, *, chunk: int = 64,
         grid=(bsz, h, nc),
         in_specs=[
             pl.BlockSpec((1,), lambda b_, h_, c_: (h_,),
-                         memory_space=_MEMSPACE.SMEM),
+                         memory_space=pltpu.MemorySpace.SMEM),
             pl.BlockSpec((1, chunk, 1, p), lambda b_, h_, c_: (b_, c_, h_, 0)),
             pl.BlockSpec((1, chunk, 1), lambda b_, h_, c_: (b_, c_, h_)),
             pl.BlockSpec((1, chunk, 1, n), lambda b_, h_, c_, _r=rep: (b_, c_, h_ // _r, 0)),

@@ -6,21 +6,21 @@ touches jax device state (the dry-run must set XLA_FLAGS before any init).
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     """v5e pod mesh: 16x16 = 256 chips per pod; 2 pods for multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, (AxisType.Auto,) * len(shape))
 
 
 def make_host_mesh() -> Mesh:
     """Whatever devices this host actually has, as a 1D data mesh (used by
     smoke tests / the CPU RL driver)."""
     n = len(jax.devices())
-    return jax.make_mesh((n,), ("data",))
+    return jax.make_mesh((n,), ("data",), (AxisType.Auto,))
 
 
 def engine_submeshes(mesh: Mesh, n_engines: int,

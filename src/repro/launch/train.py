@@ -20,6 +20,7 @@ import jax
 import numpy as np
 
 from repro.checkpoint import checkpoint
+from repro.compile_cache import enable_compile_cache
 from repro.configs.tiny import config as tiny_config
 from repro.core.algo import RLConfig
 from repro.core.conventional import ConventionalConfig, ConventionalRL
@@ -115,6 +116,7 @@ def main() -> None:
                     help="LR warmup steps (0 = constant)")
     ap.add_argument("--log-out", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.mode == "conventional" and args.max_lag is not None:
         ap.error("--max-lag is a pipeline-mode knob (conventional RL is "

@@ -22,17 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-try:  # jax >= 0.6 exports shard_map at top level
-    from jax import shard_map
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
-
-# the replication-check kwarg was renamed check_rep -> check_vma in jax 0.6
-import inspect as _inspect
-
-_SM_CHECK_KW = ("check_vma" if "check_vma"
-                in _inspect.signature(shard_map).parameters else "check_rep")
 
 from repro.configs.base import ModelConfig
 from repro.models.layers import ParamDef
@@ -148,10 +138,10 @@ def moe_apply(p, x, cfg: ModelConfig) -> Tuple[jax.Array, jax.Array]:
         }
 
         @functools.partial(
-            shard_map, mesh=mesh,
+            jax.shard_map, mesh=mesh,
             in_specs=(w_specs, x_spec),
             out_specs=(x_spec, P()),
-            **{_SM_CHECK_KW: False})
+            check_vma=False)
         def run(pl, xl):
             idx = jax.lax.axis_index("model")
             out, aux = _moe_local(pl, xl, cfg, n_local, idx * n_local, "model")

@@ -23,7 +23,7 @@ _CTX: contextvars.ContextVar = contextvars.ContextVar("shard_ctx", default=None)
 def sharding_context(mesh: Mesh, rules=None):
     tok = _CTX.set((mesh, rules))
     try:
-        with jax.sharding.use_mesh(mesh) if hasattr(jax.sharding, "use_mesh") else contextlib.nullcontext():
+        with jax.set_mesh(mesh):
             yield
     finally:
         _CTX.reset(tok)

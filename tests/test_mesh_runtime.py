@@ -42,7 +42,8 @@ KEY = jax.random.PRNGKey(3)
 
 @pytest.fixture(scope="module")
 def mesh():
-    return jax.make_mesh((N_DEV,), ("model",))
+    from jax.sharding import AxisType
+    return jax.make_mesh((N_DEV,), ("model",), (AxisType.Auto,))
 
 
 def _engine_pair_tasks():
