@@ -8,7 +8,8 @@ forced host devices on CPU — the same GSPMD partitioning either way):
     are CPU-host noise; the point is the sharded program compiles, runs,
     and stays token-identical — parity is asserted in the test suite)
   - executed streamed broadcast: per-chunk reshard+install wall time from
-    the engine's `wexec_log` vs the atomic `set_weights` transfer, and
+    the engine's `engine.install` spans vs the atomic `set_weights`
+    transfer (its `engine.swap` span), and
     the measured decode pause per weight update
   - co-sim calibration: `record_cosim_trace` replayed through the
     EventLoop twin — predicted vs measured totals and pause accounting
@@ -66,6 +67,7 @@ def mesh_benchmarks() -> List[Row]:
     from repro.data.math_task import MathTask
     from repro.launch.meshrt import record_cosim_trace, replay_trace
     from repro.launch.steps import execute_weight_update
+    from repro import spans
     from repro.models import model as M
     from repro.sharding import tree_values
 
@@ -95,16 +97,21 @@ def mesh_benchmarks() -> List[Row]:
     leaves = jax.tree_util.tree_leaves(params2)
     sizes = span_bytes(leaves, chunk_spans(leaves, N_CHUNKS))
     toks = [chunk_token(2, k, sizes[k]) for k in range(len(sizes))]
-    eng.wexec_log.clear()
+    def span_seconds(t0, name):
+        return [(r.end_ns - r.start_ns) * 1e-9
+                for r in spans.records(t0, time.perf_counter())
+                if r.name == name]
+
+    t0 = time.perf_counter()
     eng.begin_weight_stream(params2, 2, n_chunks=N_CHUNKS,
                             expect_digest=stream_digest(toks))
     for tk in toks:
         eng.stream_weight_chunk(token=tk)
     assert eng.last_stream_installed and eng.version == 2
-    chunk_s = [r["seconds"] for r in eng.wexec_log if r["kind"] == "chunk"]
-    eng.wexec_log.clear()
+    chunk_s = span_seconds(t0, "engine.install")
+    t0 = time.perf_counter()
     eng.set_weights(params, 3)
-    atomic_s = eng.wexec_log[-1]["seconds"]
+    atomic_s = span_seconds(t0, "engine.swap")[-1]
 
     # co-sim: record a real decode+install timeline, replay it in the sim
     task_c = MathTask(max_operand=5, ops="+")

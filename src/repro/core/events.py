@@ -82,6 +82,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from repro.data.packing import Rollout, pack
+from repro.spans import span
 
 
 # ---------------------------------------------------------------------------
@@ -744,7 +745,10 @@ class ActorStage:
         self.updates_applied = 0
         self.streams_completed = 0
         self.streams_aborted = 0
-        self.pause_total = 0.0             # decode pause charged to updates
+        # decode pause charged to updates by the cost model (simulated
+        # seconds, not a measurement: the host time an install takes is
+        # its `engine.install` / `engine.swap` span)
+        self.pause_total = 0.0
         self.pause_log: List[Tuple[int, float]] = []   # (version, pause)
 
     _EWMA_ALPHA = 0.25                     # per-tick progress smoothing
@@ -1037,6 +1041,10 @@ class ActorStage:
     def _tick(self, now: float, epoch: int) -> None:
         """One decode step: install weights -> (refill) -> step -> deliver
         -> (refill) -> reschedule."""
+        with span("actor.tick"):
+            self._tick_once(now, epoch)
+
+    def _tick_once(self, now: float, epoch: int) -> None:
         if epoch != self._epoch or self.failed or self.hung:
             return   # stale chain from before a crash/hang, or offline
         resume = self._preempt_until(now)
@@ -1841,6 +1849,10 @@ class TrainerStage:
         self._train(rollouts, raw_reward, avail, now, on_done)
 
     def _train(self, rollouts, raw_reward, avail, now, on_done) -> None:
+        with span("trainer.train"):
+            self._train_step(rollouts, raw_reward, avail, now, on_done)
+
+    def _train_step(self, rollouts, raw_reward, avail, now, on_done) -> None:
         start = max(now, self.free_at, avail)
         if raw_reward is None:
             raw_reward = float(np.mean([r.reward for r in rollouts]))
@@ -1850,17 +1862,19 @@ class TrainerStage:
         # staleness is computed against the version the learner steps
         # FROM (pre-step `trainer.version`), typed into the batch by
         # pack() — not recomputed ad hoc from the rollouts afterwards
-        pre_version = self.trainer.version
-        batch = pack(rollouts, self.pack_rows, self.pack_seq,
-                     trainer_version=pre_version, max_lag=self.max_lag)
-        stats = batch.pop("packing_stats")
-        trained = batch["loss_mask"] > 0
-        lag_vals = batch["lag"][trained]
-        max_lag = float(lag_vals.max()) if lag_vals.size else 0.0
-        mean_lag = float(lag_vals.mean()) if lag_vals.size else 0.0
-        for v, c in zip(*np.unique(lag_vals, return_counts=True)):
-            self.lag_hist[int(v)] = self.lag_hist.get(int(v), 0) + int(c)
-        self.lag_masked_tokens += int(stats.get("lag_masked", 0))
+        with span("trainer.sync"):
+            pre_version = self.trainer.version
+        with span("trainer.pack"):
+            batch = pack(rollouts, self.pack_rows, self.pack_seq,
+                         trainer_version=pre_version, max_lag=self.max_lag)
+            stats = batch.pop("packing_stats")
+            trained = batch["loss_mask"] > 0
+            lag_vals = batch["lag"][trained]
+            max_lag = float(lag_vals.max()) if lag_vals.size else 0.0
+            mean_lag = float(lag_vals.mean()) if lag_vals.size else 0.0
+            for v, c in zip(*np.unique(lag_vals, return_counts=True)):
+                self.lag_hist[int(v)] = self.lag_hist.get(int(v), 0) + int(c)
+            self.lag_masked_tokens += int(stats.get("lag_masked", 0))
         # pre-step snapshot (free: the state is not donated, this is a
         # tuple of references) — crash() rolls back to it so the eagerly
         # computed step is truly lost if the trainer dies before `done`
@@ -1868,21 +1882,25 @@ class TrainerStage:
         # host batch goes straight in: the trainer stages it with one
         # jitted donated transfer; returned metrics are device-resident
         # and sync only when the log entry below reads them
-        if self._poison_pending > 0:
-            self._poison_pending -= 1
-            metrics = self.trainer.step(batch, poison=True)
-        else:
-            metrics = self.trainer.step(batch)
+        with span("trainer.step", rows=int(batch["tokens"].shape[0]),
+                  loss_tokens=int(trained.sum()), version=int(pre_version)):
+            if self._poison_pending > 0:
+                self._poison_pending -= 1
+                metrics = self.trainer.step(batch, poison=True)
+            else:
+                metrics = self.trainer.step(batch)
         # §10 bad-step policy: a non-finite step was already dropped
         # inside the jitted step (skip-and-count — state and version are
         # untouched); the optional loss-spike detector flags silent
         # divergence. Either way the step consumed its batch and its
         # wall-time, and `consecutive_bad` arms the rollback.
-        bad = bool(getattr(self.trainer, "guard", False)) \
-            and self.trainer.last_nonfinite()
+        with span("trainer.sync"):
+            bad = bool(getattr(self.trainer, "guard", False)) \
+                and self.trainer.last_nonfinite()
         if not bad and self.loss_spike_factor > 0.0:
-            loss = (metrics.peek("loss") if hasattr(metrics, "peek")
-                    else float(metrics["loss"]))
+            with span("trainer.sync"):
+                loss = (metrics.peek("loss") if hasattr(metrics, "peek")
+                        else float(metrics["loss"]))
             if self._loss_ewma is not None and \
                     abs(loss) > self.loss_spike_factor * \
                     max(abs(self._loss_ewma), 1e-8):
@@ -1899,7 +1917,8 @@ class TrainerStage:
             self.consecutive_bad = 0
         n_tokens = sum(r.length for r in rollouts)
         done = start + self.train_time(n_tokens)
-        version = self.trainer.version
+        with span("trainer.sync"):
+            version = self.trainer.version
         stall = 0.0
         do_ckpt = bool(self.ckpt_every and not bad
                        and version % self.ckpt_every == 0)
@@ -1920,8 +1939,9 @@ class TrainerStage:
             "queue_depth": queue_depth,
             "stall": stall,
             "bad_step": float(bad),
-            **metrics,
         }
+        with span("trainer.sync"):
+            entry.update(metrics)
         if self.max_lag is not None:
             entry["lag_masked"] = int(stats.get("lag_masked", 0))
         self.log.append(entry)
@@ -2112,11 +2132,16 @@ class WeightBroadcaster:
         return arrivals, tokens
 
     def publish(self, params, version: int, now: float) -> None:
+        with span("trainer.publish", version=int(version)) as counts:
+            self._publish(params, version, now, counts)
+
+    def _publish(self, params, version: int, now: float,
+                 counts: Dict[str, int]) -> None:
         self.published += 1
         targets = [(i, a) for i, a in enumerate(self.actors)
                    if not getattr(a, "failed", False)]
         self.deliveries_skipped += len(self.actors) - len(targets)
-        nbytes = tree_bytes(params)
+        nbytes = counts["nbytes"] = tree_bytes(params)
         self.bytes_published += nbytes * len(targets)
         if self.mode == "free":
             for _, a in targets:

@@ -18,7 +18,6 @@ import collections
 import contextlib
 import dataclasses
 import functools
-import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
@@ -33,6 +32,7 @@ from repro.data.packing import Rollout
 from repro.kernels.paged_cache import BlockTables, OutOfPages, PageAllocator
 from repro.models import attention as attn
 from repro.models import model as M
+from repro.spans import named, span
 
 
 @dataclasses.dataclass
@@ -239,9 +239,6 @@ class GenerationEngine:
         self.mesh, self.rules = mesh, rules
         self._param_shardings = None
         self._pshard_leaves: Optional[List[Any]] = None
-        # executed-transfer log, one entry per measured device placement:
-        # {"kind": "atomic"|"chunk", "version", "k", "nbytes", "seconds"}
-        self.wexec_log: List[Dict[str, Any]] = []
         if mesh is not None:
             from repro.sharding import tree_shardings
             ann = M.init_params(cfg, abstract=True)
@@ -369,16 +366,21 @@ class GenerationEngine:
                 self._prefill = jit_donor._prefill
                 self._use_prefill_hint = jit_donor._use_prefill_hint
             return
-        self._step = jax.jit(functools.partial(_engine_step, cfg=cfg, ec=ec),
-                             static_argnames=("kv_len_hint",))
+        # named programs: `jit_engine_decode` etc. in HLO and in traces
+        self._step = jax.jit(
+            named(functools.partial(_engine_step, cfg=cfg, ec=ec),
+                  "engine_decode"), static_argnames=("kv_len_hint",))
         rc = (self._recompute_impl_paged if self._paged
               else self._recompute_impl)
-        self._recompute = jax.jit(functools.partial(rc, cfg=cfg))
-        self._admit = jax.jit(functools.partial(_admit_impl, cfg=cfg),
-                              donate_argnums=(0,))
+        self._recompute = jax.jit(named(functools.partial(rc, cfg=cfg),
+                                        "engine_recompute"))
+        self._admit = jax.jit(
+            named(functools.partial(_admit_impl, cfg=cfg), "engine_admit"),
+            donate_argnums=(0,))
         if chunk:
             self._prefill = jax.jit(
-                functools.partial(_prefill_impl, cfg=cfg, chunk=chunk),
+                named(functools.partial(_prefill_impl, cfg=cfg, chunk=chunk),
+                      "engine_prefill"),
                 donate_argnums=(1,), static_argnames=("offset_hint",))
             # hint buckets only matter when the Pallas prefill kernel runs
             # (each bucket is one extra compile of the chunk forward)
@@ -423,37 +425,35 @@ class GenerationEngine:
         atomic swap supersedes any in-progress weight stream.
 
         On a mesh engine the swap is an *executed* transfer: the incoming
-        tree is resharded onto this engine's placement and the measured
-        wall time lands in `wexec_log` (`_placed=True` skips the copy when
-        the caller already delivered device-resident buffers, e.g. the
-        final swap of an executed chunk stream)."""
-        self._wstream = None
-        if self.mesh is not None and not _placed:
-            from repro.core.events import tree_bytes
-            t0 = time.perf_counter()
-            params = jax.device_put(params, self._param_shardings)
-            jax.block_until_ready(params)
-            self.wexec_log.append({
-                "kind": "atomic", "version": int(version), "k": -1,
-                "nbytes": tree_bytes(params),
-                "seconds": time.perf_counter() - t0})
-        self.params = params
-        self.version = version
-        if recompute_kv:
-            if self._paged:
-                # unshare every shared block first: the recompute scatter
-                # overwrites all positions of every referenced page, which
-                # must not clobber a page other forks still read — and a
-                # page referenced twice in one scatter would be written
-                # nondeterministically
-                self._unshare_all()
-                self._sync_tables()
-                with self._ctx():
-                    self.state["cache"] = self._recompute(
-                        params, self.state, self._bt_jax)
-            else:
-                with self._ctx():
-                    self.state["cache"] = self._recompute(params, self.state)
+        tree is resharded onto this engine's placement and waited for, so
+        the `engine.swap` span measures it (`_placed=True` skips the copy
+        when the caller already delivered device-resident buffers, e.g.
+        the final swap of an executed chunk stream)."""
+        from repro.core.events import tree_bytes
+        with span("engine.swap", version=int(version),
+                  nbytes=tree_bytes(params)):
+            self._wstream = None
+            if self.mesh is not None and not _placed:
+                params = jax.device_put(params, self._param_shardings)
+                jax.block_until_ready(params)
+            self.params = params
+            self.version = version
+            if recompute_kv:
+                if self._paged:
+                    # unshare every shared block first: the recompute scatter
+                    # overwrites all positions of every referenced page, which
+                    # must not clobber a page other forks still read — and a
+                    # page referenced twice in one scatter would be written
+                    # nondeterministically
+                    self._unshare_all()
+                    self._sync_tables()
+                    with self._ctx():
+                        self.state["cache"] = self._recompute(
+                            params, self.state, self._bt_jax)
+                else:
+                    with self._ctx():
+                        self.state["cache"] = self._recompute(params,
+                                                              self.state)
 
     def begin_weight_stream(self, params, version: int, n_chunks: int = 8,
                             recompute_kv: bool = False,
@@ -509,44 +509,43 @@ class GenerationEngine:
             if token != chunk_token(ws["version"], k, ws["sizes"][k]):
                 self.wchunks_rejected += 1
                 return False
-        lo, hi = ws["spans"][k]
-        if ws.get("chunk_leaves") is not None:
-            # executor-resharded span: the buffers already live on this
-            # engine's devices (k-indexed, so a retransmit after a
-            # rejected chunk naturally reuses the right span)
-            ws["shadow"][lo:hi] = list(ws["chunk_leaves"][k])
-        elif self.mesh is not None:
-            # in-engine executed transfer: reshard the span onto this
-            # engine's placement, measured (DESIGN.md §11)
-            t0 = time.perf_counter()
-            placed = jax.device_put(ws["leaves"][lo:hi],
-                                    self._pshard_leaves[lo:hi])
-            jax.block_until_ready(placed)
-            self.wexec_log.append({
-                "kind": "chunk", "version": int(ws["version"]), "k": k,
-                "nbytes": ws["sizes"][k],
-                "seconds": time.perf_counter() - t0})
-            ws["shadow"][lo:hi] = placed
-        else:
-            ws["shadow"][lo:hi] = ws["leaves"][lo:hi]
-        ws["tokens"].append(chunk_token(ws["version"], k, ws["sizes"][k]))
-        ws["next"] += 1
-        if ws["next"] < len(ws["spans"]):
-            return False
-        torn = any(x is None for x in ws["shadow"]) or (
-            ws["expect"] is not None
-            and stream_digest(ws["tokens"]) != ws["expect"])
-        if torn:
-            self.wstreams_torn += 1
-            self.last_stream_installed = False
-            self._wstream = None
+        with span("engine.install", version=int(ws["version"]), chunk=k,
+                  nbytes=ws["sizes"][k], swapped=0) as counts:
+            lo, hi = ws["spans"][k]
+            if ws.get("chunk_leaves") is not None:
+                # executor-resharded span: the buffers already live on this
+                # engine's devices (k-indexed, so a retransmit after a
+                # rejected chunk naturally reuses the right span)
+                ws["shadow"][lo:hi] = list(ws["chunk_leaves"][k])
+            elif self.mesh is not None:
+                # in-engine executed transfer: reshard the span onto this
+                # engine's placement and wait for it, so the `engine.install`
+                # span measures it (DESIGN.md §11)
+                placed = jax.device_put(ws["leaves"][lo:hi],
+                                        self._pshard_leaves[lo:hi])
+                jax.block_until_ready(placed)
+                ws["shadow"][lo:hi] = placed
+            else:
+                ws["shadow"][lo:hi] = ws["leaves"][lo:hi]
+            ws["tokens"].append(chunk_token(ws["version"], k, ws["sizes"][k]))
+            ws["next"] += 1
+            if ws["next"] < len(ws["spans"]):
+                return False
+            torn = any(x is None for x in ws["shadow"]) or (
+                ws["expect"] is not None
+                and stream_digest(ws["tokens"]) != ws["expect"])
+            if torn:
+                self.wstreams_torn += 1
+                self.last_stream_installed = False
+                self._wstream = None
+                return True
+            params = jax.tree_util.tree_unflatten(ws["treedef"], ws["shadow"])
+            version, recompute = ws["version"], ws["recompute"]
+            self.last_stream_installed = True
+            counts["swapped"] = 1
+            self.set_weights(params, version, recompute_kv=recompute,
+                             _placed=True)
             return True
-        params = jax.tree_util.tree_unflatten(ws["treedef"], ws["shadow"])
-        version, recompute = ws["version"], ws["recompute"]
-        self.last_stream_installed = True
-        self.set_weights(params, version, recompute_kv=recompute,
-                         _placed=True)
-        return True
 
     @property
     def stream_active(self) -> bool:
@@ -832,6 +831,15 @@ class GenerationEngine:
         free = np.where(~self._host_active)[0]
         if free.size == 0:
             return 0
+        calls0 = self.prefill_invocations
+        with span("engine.admit") as counts:
+            n = self._admit_into(free, now)
+            counts.update(admitted=n,
+                          prefill_tokens=self.last_admit_prefill_tokens,
+                          prefill_calls=self.prefill_invocations - calls0)
+        return n
+
+    def _admit_into(self, free: np.ndarray, now: float) -> int:
         H, T = self.ec.n_slots, self.ec.max_len
         new_tokens = np.full((H, T), self.ec.pad_id, np.int32)
         new_plen = np.zeros(H, np.int32)
@@ -976,73 +984,89 @@ class GenerationEngine:
              now: float = 0.0) -> List[Rollout]:
         """Generate one token on every active slot; returns rollouts that
         finished this step."""
-        if self._paged:
-            # host-side COW hook: every active slot's next write lands on
-            # an exclusively-owned page (may preempt a slot on OutOfPages,
-            # which deactivates it before the mirrors are snapshotted)
-            self._prepare_pages_for_step()
-        prev_active = self._host_active.copy()
-        prev_ncached = self._host_ncached.copy()
-        # grid-level early exit for flash-decode: bound the valid cache
-        # length from the host mirrors, rounded up to the kernel's block
-        # size so jit sees at most CL/block distinct static values. Only
-        # active slots count — an idle slot's stale high count would pin
-        # the hint at capacity; inactive rows' (possibly truncated)
-        # attention outputs are discarded by the `active` gating anyway.
-        hint = None
-        if self._use_decode_hint:
-            cl = self._cache_len
-            blk = attn.decode_block_k(cl)
-            cur = (int(self._host_ncached[self._host_active].max()) + 1
-                   if self._host_active.any() else 1)
-            hint = int(min(cl, -(-cur // blk) * blk))
-        with self._ctx():
-            self.state, finished = self._step(self.params, self.state,
-                                              self._bt_jax,
-                                              kv_len_hint=hint)
-        finished = np.asarray(finished)
-        # record weight version for tokens written this step — only tokens
-        # actually *sampled* under μ; prompt-forced tokens keep version 0
-        # so token-lag stats can't be diluted by the prompt mask convention
-        nxt = prev_ncached + 1
-        wrote = (prev_active & (nxt < self.ec.max_len)
-                 & (nxt >= self._host_prompt_len))
-        self.ver_buf[wrote, nxt[wrote]] = self.version
-        self.tokens_generated += int(prev_active.sum())
-        # advance host mirrors (device does n_cached+1 on active slots)
-        self._host_ncached[prev_active] += 1
-        self._host_active[finished] = False
+        with span("engine.decode") as counts:
+            if self._paged:
+                # host-side COW hook: every active slot's next write lands on
+                # an exclusively-owned page (may preempt a slot on OutOfPages,
+                # which deactivates it before the mirrors are snapshotted)
+                self._prepare_pages_for_step()
+            prev_active = self._host_active.copy()
+            prev_ncached = self._host_ncached.copy()
+            n_active = int(prev_active.sum())
+            counts.update(active=n_active,
+                          ctx=int((prev_ncached[prev_active] + 1).sum()))
+            # grid-level early exit for flash-decode: bound the valid cache
+            # length from the host mirrors, rounded up to the kernel's block
+            # size so jit sees at most CL/block distinct static values. Only
+            # active slots count — an idle slot's stale high count would pin
+            # the hint at capacity; inactive rows' (possibly truncated)
+            # attention outputs are discarded by the `active` gating anyway.
+            hint = None
+            if self._use_decode_hint:
+                cl = self._cache_len
+                blk = attn.decode_block_k(cl)
+                cur = (int(self._host_ncached[self._host_active].max()) + 1
+                       if self._host_active.any() else 1)
+                hint = int(min(cl, -(-cur // blk) * blk))
+            with span("engine.decode.dispatch"), self._ctx():
+                self.state, finished = self._step(self.params, self.state,
+                                                  self._bt_jax,
+                                                  kv_len_hint=hint)
+            with span("engine.decode.wait"):
+                finished = np.asarray(finished)
+            # record weight version for tokens written this step — only
+            # tokens actually *sampled* under μ; prompt-forced tokens keep
+            # version 0 so token-lag stats can't be diluted by the prompt
+            # mask convention
+            nxt = prev_ncached + 1
+            wrote = (prev_active & (nxt < self.ec.max_len)
+                     & (nxt >= self._host_prompt_len))
+            self.ver_buf[wrote, nxt[wrote]] = self.version
+            self.tokens_generated += n_active
+            # advance host mirrors (device does n_cached+1 on active slots)
+            self._host_ncached[prev_active] += 1
+            self._host_active[finished] = False
 
+            if not finished.any():
+                return []
+            with span("engine.decode.finish",
+                      finished=int(finished.sum())):
+                return self._finished_rollouts(finished, task, now)
+
+    def _finished_rollouts(self, finished: np.ndarray,
+                           task: Optional[MathTask],
+                           now: float) -> List[Rollout]:
+        """Read back the finished slots' tokens and logprobs, score
+        them and build their `Rollout`s."""
         done: List[Rollout] = []
-        if finished.any():
-            tokens = np.asarray(self.state["tokens"])
-            lp = np.asarray(self.state["lp"])
-            for s in np.where(finished)[0]:
-                if self._paged:
-                    # finished slots return their pages (shared-prefix
-                    # pages only truly free once every fork finishes)
-                    self._release_slot_pages(int(s))
-                L = int(self._host_ncached[s]) + 1  # incl. just-sampled token
-                L = min(L, self.ec.max_len)
-                prob = self.problems[s]
-                pl = int(self._host_prompt_len[s])
-                completion = tokens[s, pl:L]
-                reward = 0.0
-                if task is not None and prob is not None:
-                    reward = task.reward(prob, completion,
-                                         self.ec.max_len - pl)
-                done.append(Rollout(
-                    tokens=tokens[s, :L].copy(),
-                    prompt_len=pl,
-                    behavior_logprobs=lp[s, :L].copy(),
-                    reward=reward,
-                    weight_versions=self.ver_buf[s, :L].copy(),
-                    finished_at=now,
-                    prompt_key=(hash(tuple(prob.prompt_ids)) & 0x7FFFFFFF
-                                if prob is not None else 0),
-                    slot=int(s),
-                    truncated=bool(tokens[s, L - 1] != self.ec.eos_id),
-                ))
+        tokens = np.asarray(self.state["tokens"])
+        lp = np.asarray(self.state["lp"])
+        for s in np.where(finished)[0]:
+            if self._paged:
+                # finished slots return their pages (shared-prefix
+                # pages only truly free once every fork finishes)
+                self._release_slot_pages(int(s))
+            L = int(self._host_ncached[s]) + 1  # incl. just-sampled token
+            L = min(L, self.ec.max_len)
+            prob = self.problems[s]
+            pl = int(self._host_prompt_len[s])
+            completion = tokens[s, pl:L]
+            reward = 0.0
+            if task is not None and prob is not None:
+                reward = task.reward(prob, completion,
+                                     self.ec.max_len - pl)
+            done.append(Rollout(
+                tokens=tokens[s, :L].copy(),
+                prompt_len=pl,
+                behavior_logprobs=lp[s, :L].copy(),
+                reward=reward,
+                weight_versions=self.ver_buf[s, :L].copy(),
+                finished_at=now,
+                prompt_key=(hash(tuple(prob.prompt_ids)) & 0x7FFFFFFF
+                            if prob is not None else 0),
+                slot=int(s),
+                truncated=bool(tokens[s, L - 1] != self.ec.eos_id),
+            ))
         return done
 
     def oldest_inflight_version(self) -> Optional[int]:

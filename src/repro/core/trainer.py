@@ -25,6 +25,7 @@ from repro.configs.base import ModelConfig
 from repro.core.algo import RLConfig, reinforce_loss
 from repro.models import model as M
 from repro.optim.adam import AdamConfig, AdamState, adam_init, adam_update
+from repro.spans import named
 
 
 class TrainState(NamedTuple):
@@ -142,7 +143,8 @@ def make_train_step(cfg: ModelConfig, rl: RLConfig, adam: AdamConfig,
     fn = functools.partial(train_step, cfg=cfg, rl=rl, adam=adam,
                            microbatch=microbatch, lr_schedule=lr_schedule,
                            guard=guard)
-    return jax.jit(fn, donate_argnums=(0,) if donate else ())
+    return jax.jit(named(fn, "train_step"),
+                   donate_argnums=(0,) if donate else ())
 
 
 class LazyMetrics(Mapping):
@@ -227,13 +229,16 @@ class Trainer:
         # step; explicit donation would add nothing (XLA donation aliases
         # inputs to *outputs* only, and a consumed batch has no matching
         # output — it would just warn "donated buffers were not usable").
+        def trainer_stage_batch(b):
+            return b
+
         if mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec
             self._stage = jax.jit(
-                lambda b: b,
+                trainer_stage_batch,
                 out_shardings=NamedSharding(mesh, PartitionSpec()))
         else:
-            self._stage = jax.jit(lambda b: b)
+            self._stage = jax.jit(trainer_stage_batch)
         self.history: List[LazyMetrics] = []
 
     def _ctx(self):

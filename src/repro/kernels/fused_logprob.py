@@ -276,6 +276,7 @@ def _fused_fwd_call(hidden, head, targets, block_n, block_v, transpose_head,
         out_shape=[jax.ShapeDtypeStruct((N, 1), jnp.float32)] * 3,
         scratch_shapes=[pltpu.VMEM((bn, 1), jnp.float32)] * 4,
         interpret=interpret,
+        name="fused_logprob_fwd",
     )(tgt, hidden, head)
     lp, lse, ent = (o[:, 0] for o in out)
     return lp, lse, ent
@@ -305,6 +306,7 @@ def _fused_bwd_call(hidden, head, targets, lse, c0, g_lp, g_ent,
         out_shape=jax.ShapeDtypeStruct((N, D), hidden.dtype),
         scratch_shapes=[pltpu.VMEM((bn, D), jnp.float32)],
         interpret=interpret,
+        name="fused_logprob_bwd_dh",
     )(*rows, hidden, head_p)
 
     dw_shape = (Vp, D) if transpose_head else (D, Vp)
@@ -333,6 +335,7 @@ def _fused_bwd_call(hidden, head, targets, lse, c0, g_lp, g_ent,
                                            jnp.float32),
             scratch_shapes=[pltpu.VMEM(dw_block, jnp.float32)],
             interpret=interpret,
+            name="fused_logprob_bwd_dw",
         )(*rows, hidden, head_p)
         dw = dw_part.sum(axis=0).astype(head.dtype)
     else:
@@ -351,6 +354,7 @@ def _fused_bwd_call(hidden, head, targets, lse, c0, g_lp, g_ent,
             out_shape=jax.ShapeDtypeStruct(dw_shape, head.dtype),
             scratch_shapes=[pltpu.VMEM(dw_block, jnp.float32)],
             interpret=interpret,
+            name="fused_logprob_bwd_dw",
         )(*rows, hidden, head_p)
     if Vp != V:
         dw = dw[:V] if transpose_head else dw[:, :V]

@@ -155,7 +155,11 @@ def test_executed_stream_bitwise_with_integrity_gate(mesh):
     """Streamed install on real device buffers: a corrupt chunk token is
     rejected (no partial state change), the retransmit succeeds, and the
     final params are bit-identical to an atomic `set_weights` of the same
-    tree. Every chunk leaves a measured transfer in wexec_log."""
+    tree. Every chunk installed leaves a measured `engine.install` span,
+    the last with the swap nested in it."""
+    import time
+
+    from repro import spans
     from repro.core.events import (chunk_spans, chunk_token, span_bytes,
                                    stream_digest)
     from repro.core.rollout import EngineConfig, GenerationEngine
@@ -171,6 +175,7 @@ def test_executed_stream_bitwise_with_integrity_gate(mesh):
     good = [chunk_token(7, k, sizes[k]) for k in range(len(sizes))]
     eng.begin_weight_stream(params2, 7, n_chunks=4,
                             expect_digest=stream_digest(good))
+    t0 = time.perf_counter()
     done, k = False, 0
     while not done:
         tok = good[min(k, len(good) - 1)]
@@ -180,9 +185,16 @@ def test_executed_stream_bitwise_with_integrity_gate(mesh):
         done = eng.stream_weight_chunk(token=tok)
         k += 1
     assert eng.last_stream_installed and eng.version == 7
-    chunk_recs = [r for r in eng.wexec_log if r["kind"] == "chunk"]
+    recs = spans.records(t0, time.perf_counter())
+    chunk_recs = [r for r in recs if r.name == "engine.install"]
     assert len(chunk_recs) == 4
-    assert all(r["seconds"] > 0 for r in chunk_recs)
+    assert all(r.end_ns > r.start_ns for r in chunk_recs)
+    assert [r.counts["chunk"] for r in chunk_recs] == [0, 1, 2, 3]
+    assert sum(r.counts["nbytes"] for r in chunk_recs) == sum(sizes)
+    assert [r.counts["swapped"] for r in chunk_recs] == [0, 0, 0, 1]
+    swaps = [r for r in recs if r.name == "engine.swap"]
+    assert [(r.parent, r.counts["version"]) for r in swaps] == [
+        ("engine.install", 7)]
 
     ref.set_weights(params2, 7)
     for a, b in zip(jax.tree_util.tree_leaves(eng.params),

@@ -104,6 +104,23 @@ def test_kernel_compiles_for_v5e(one_chip, name):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("name, kernels", [
+    ("flash_decode", ("flash_decode",)),
+    ("fused_logprob_forward", ("fused_logprob_fwd",)),
+    ("fused_logprob_grad", ("fused_logprob_fwd", "fused_logprob_bwd_dh",
+                            "fused_logprob_bwd_dw"))])
+def test_kernels_keep_their_names_for_v5e(one_chip, name, kernels):
+    """The Mosaic calls carry the kernels' names into the compiled program
+    (and so into profiler traces), under JAX's transformations too."""
+    fn, *shapes = _kernel_case(name)
+    text = _compile_text(one_chip, fn, *shapes)
+    calls = [line.strip().split(" = ")[0] for line in text.splitlines()
+             if "custom-call(" in line and "tpu_custom_call" in line]
+    assert len(calls) == len(kernels)
+    for k in kernels:
+        assert any(k in c for c in calls), (k, calls)
+
+
 def test_engine_decode_step_compiles_for_v5e(one_chip):
     """One generation-engine decode step (4 layers, 32 slots, cache 1024)
     compiles with the flash-decode kernel inside."""
