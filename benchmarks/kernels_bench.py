@@ -29,8 +29,8 @@ def kernel_benchmarks() -> List[Row]:
     rows.append(("kernel/flash_attention_256", us, f"flops={flops:.0f}"))
 
     CL = 512
-    kc = jax.random.normal(ks[1], (B, CL, KV, D))
-    vc = jax.random.normal(ks[2], (B, CL, KV, D))
+    kc = jax.random.normal(ks[1], (1, B, KV, CL, D))   # one layer's stack
+    vc = jax.random.normal(ks[2], (1, B, KV, CL, D))
     qd = jax.random.normal(ks[0], (B, H, D))
     us, _ = time_call(ops.flash_decode, qd, kc, vc,
                       jnp.full((B,), CL), scale=D ** -0.5, iters=3)

@@ -230,7 +230,12 @@ def for_shape(cfg: ModelConfig, shape: ShapeSpec) -> ModelConfig:
 
 
 def kv_cache_specs(cfg: ModelConfig, batch: int, cache_len: int) -> Dict[str, Any]:
-    """ShapeDtypeStructs for the decode-state pytree (stacked over layers)."""
+    """ShapeDtypeStructs for the decode-state pytree (stacked over layers).
+
+    GQA K/V are head-major, (L, B, KV, CL, D): the layout the decode and
+    prefill kernels read, so no step swaps a layer into it. MLA latents
+    (L, B, CL, r) have no head axis. `CACHE_LOGICAL` names the axes and
+    `cache_seq_axis` finds the ring axis of each leaf."""
     L = cfg.n_layers
     s: Dict[str, Any] = {}
     if cfg.has_attention:
@@ -239,8 +244,8 @@ def kv_cache_specs(cfg: ModelConfig, batch: int, cache_len: int) -> Dict[str, An
             s["c_kv"] = jax.ShapeDtypeStruct((L, batch, cl, cfg.kv_lora_rank), cfg.dtype)
             s["k_rope"] = jax.ShapeDtypeStruct((L, batch, cl, cfg.qk_rope_dim), cfg.dtype)
         else:
-            s["k"] = jax.ShapeDtypeStruct((L, batch, cl, cfg.n_kv_heads, cfg.d_head), cfg.dtype)
-            s["v"] = jax.ShapeDtypeStruct((L, batch, cl, cfg.n_kv_heads, cfg.d_head), cfg.dtype)
+            s["k"] = jax.ShapeDtypeStruct((L, batch, cfg.n_kv_heads, cl, cfg.d_head), cfg.dtype)
+            s["v"] = jax.ShapeDtypeStruct((L, batch, cfg.n_kv_heads, cl, cfg.d_head), cfg.dtype)
     if cfg.has_ssm:
         s["conv"] = jax.ShapeDtypeStruct(
             (L, batch, cfg.d_conv - 1,
@@ -332,13 +337,18 @@ def input_specs(cfg: ModelConfig, shape: ShapeSpec) -> Dict[str, Any]:
 
 
 CACHE_LOGICAL = {
-    "k": ("layers", "batch", "cache_seq", "kv_heads", None),
-    "v": ("layers", "batch", "cache_seq", "kv_heads", None),
+    "k": ("layers", "batch", "kv_heads", "cache_seq", None),
+    "v": ("layers", "batch", "kv_heads", "cache_seq", None),
     "c_kv": ("layers", "batch", "cache_seq", None),
     "k_rope": ("layers", "batch", "cache_seq", None),
     "conv": ("layers", "batch", None, "mlp"),
     "ssd": ("layers", "batch", "heads", None, None),
 }
+
+
+def cache_seq_axis(key: str) -> int:
+    """Ring (cache position) axis of a stacked slot-cache leaf."""
+    return CACHE_LOGICAL[key].index("cache_seq")
 
 
 def input_logical(cfg: ModelConfig, shape: ShapeSpec) -> Dict[str, Any]:

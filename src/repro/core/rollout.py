@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs.base import (CACHE_LOGICAL, ModelConfig,
+from repro.configs.base import (CACHE_LOGICAL, ModelConfig, cache_seq_axis,
                                 effective_cache_len, kv_cache_specs,
                                 paged_cache_specs, paged_layout)
 from repro.data.math_task import MathTask, Problem
@@ -106,16 +106,16 @@ def _zero_paged_cache(cfg: ModelConfig, n_slots: int, max_len: int,
 
 
 def _paged_ring_view(cache, block_tables):
-    """Gather pool leaves (L,NP,PS,...) into slot-layout (L,H,CL,...)
-    views through the block table; SSM leaves (already per-slot) pass
-    through untouched."""
+    """Gather pool leaves (L,NP,PS,...) into slot-layout views through the
+    block table: (L,H,CL,...), K/V head-major (L,H,KV,CL,D); SSM leaves
+    (already per-slot) pass through untouched."""
     out = dict(cache)
     for k in ("k", "v", "c_kv", "k_rope"):
         if k in out:
             v = jnp.take(out[k], block_tables, axis=1)    # (L,H,NB,PS,...)
-            out[k] = v.reshape(
-                (v.shape[0], v.shape[1], v.shape[2] * v.shape[3])
-                + v.shape[4:])
+            v = v.reshape((v.shape[0], v.shape[1], v.shape[2] * v.shape[3])
+                          + v.shape[4:])
+            out[k] = jnp.moveaxis(v, 2, cache_seq_axis(k))
     return out
 
 
@@ -216,6 +216,16 @@ def _engine_step(params, st: Dict[str, Any], block_tables,
     return new_st, finished
 
 
+def decode_program(cfg: ModelConfig, ec: EngineConfig):
+    """The engine's jitted decode step (`jit_engine_decode`). The state is
+    donated, so the slot cache is updated in place from one step to the
+    next."""
+    return jax.jit(
+        named(functools.partial(_engine_step, cfg=cfg, ec=ec),
+              "engine_decode"), static_argnames=("kv_len_hint",),
+        donate_argnums=(1,))
+
+
 class GenerationEngine:
     """H-slot continuous-batching engine (Algorithm 2, Actor).
 
@@ -298,20 +308,13 @@ class GenerationEngine:
         self._host_ncached = np.zeros(H, np.int64)
         self._host_prompt_len = np.ones(H, np.int64)
         # attention cache length (None for attention-free archs); a ring
-        # buffer when < T (sliding-window long-context decode). In paged
-        # mode the leaves are (L,NP,PS,...) pools, so the logical length
-        # comes from the layout, not the leaf shape.
+        # buffer when < T (sliding-window long-context decode)
         self._cache_len: Optional[int] = None
         if cfg.has_attention:
+            self._cache_len = effective_cache_len(cfg, T)
             if self._paged:
-                self._cache_len = (self.tables.n_blocks
-                                   * self.allocator.page_size)
-                assert self._cache_len == effective_cache_len(cfg, T)
-            else:
-                self._cache_len = (
-                    self.state["cache"]["k"].shape[2]
-                    if "k" in self.state["cache"]
-                    else self.state["cache"]["c_kv"].shape[2])
+                assert self._cache_len == (self.tables.n_blocks
+                                           * self.allocator.page_size)
         # the decode-length hint only matters when gqa_decode actually
         # takes the flash-decode kernel path; computing it otherwise would
         # re-trace the jitted step once per hint bucket for no benefit
@@ -366,10 +369,10 @@ class GenerationEngine:
                 self._prefill = jit_donor._prefill
                 self._use_prefill_hint = jit_donor._use_prefill_hint
             return
-        # named programs: `jit_engine_decode` etc. in HLO and in traces
-        self._step = jax.jit(
-            named(functools.partial(_engine_step, cfg=cfg, ec=ec),
-                  "engine_decode"), static_argnames=("kv_len_hint",))
+        # named programs: `jit_engine_decode` etc. in HLO and in traces.
+        # Decode, admission and prefill donate the state: the slot cache
+        # is updated in place (nothing reads a donated state afterwards)
+        self._step = decode_program(cfg, ec)
         rc = (self._recompute_impl_paged if self._paged
               else self._recompute_impl)
         self._recompute = jax.jit(named(functools.partial(rc, cfg=cfg),
@@ -764,7 +767,7 @@ class GenerationEngine:
                 continue
             pool = new[k]                         # (L,NP,PS,...)
             L, NP, PS = pool.shape[:3]
-            v = view[k]                           # (L,H,CL,...)
+            v = jnp.moveaxis(view[k], cache_seq_axis(k), 2)  # (L,H,CL,...)
             vr = v.reshape((L, v.shape[1], NB, PS) + v.shape[3:])
             new[k] = pool.at[:, block_tables].set(vr.astype(pool.dtype))
         return new
@@ -782,7 +785,7 @@ class GenerationEngine:
                 continue
             if k in ("conv", "ssd"):
                 continue  # recurrent state recompute not supported here
-            full = out["cache"][k]            # (L,H,T,...) full-length
+            full = out["cache"][k]         # full length: T on the ring axis
             if full.shape == new[k].shape:
                 new[k] = full.astype(new[k].dtype)
                 continue
@@ -794,16 +797,17 @@ class GenerationEngine:
             # Rows with n_cached <= CL reduce to p_j = j for live slots;
             # slots beyond a row's frontier clamp to dead positions that
             # count-based decode masking never reads.
-            CL = new[k].shape[2]
+            ax = cache_seq_axis(k)
+            CL = new[k].shape[ax]
             nc = st["n_cached"][None, :, None]              # (1,H,1)
             j = jnp.arange(CL)[None, None]                  # (1,1,CL)
             p = (nc - 1) - jnp.mod(nc - 1 - j, CL)          # (1,H,CL)
             p = jnp.clip(p, 0, T - 1)
-            idx = p.reshape(p.shape + (1,) * (full.ndim - 3))
+            idx = jnp.moveaxis(p.reshape(p.shape + (1,) * (full.ndim - 3)),
+                               2, ax)
             new[k] = jnp.take_along_axis(
-                full, jnp.broadcast_to(
-                    idx, full.shape[:2] + (CL,) + full.shape[3:]),
-                axis=2).astype(new[k].dtype)
+                full, jnp.broadcast_to(idx, new[k].shape),
+                axis=ax).astype(new[k].dtype)
         return new
 
     # ----- admission ----------------------------------------------------
@@ -993,8 +997,12 @@ class GenerationEngine:
             prev_active = self._host_active.copy()
             prev_ncached = self._host_ncached.copy()
             n_active = int(prev_active.sum())
+            # kv_inplace: this step writes its K/V rows into the slot
+            # cache in place (0: paged pools, or no attention cache)
             counts.update(active=n_active,
-                          ctx=int((prev_ncached[prev_active] + 1).sum()))
+                          ctx=int((prev_ncached[prev_active] + 1).sum()),
+                          kv_inplace=int(self._cache_len is not None
+                                         and not self._paged))
             # grid-level early exit for flash-decode: bound the valid cache
             # length from the host mirrors, rounded up to the kernel's block
             # size so jit sees at most CL/block distinct static values. Only

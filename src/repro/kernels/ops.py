@@ -46,21 +46,25 @@ def flash_attention(q, k, v, *, scale: float, causal: bool = True,
 
 @functools.partial(jax.jit, static_argnames=("scale", "block_k",
                                              "max_len_hint", "interpret"))
-def flash_decode(q, k_cache, v_cache, lengths, *, scale: float,
+def flash_decode(q, k_cache, v_cache, lengths, layer=0, *, scale: float,
                  block_k: int = 256, max_len_hint: int | None = None,
                  interpret: bool | None = None):
     """One-token decode attention against the (possibly ring-buffer) slot
     cache — the generation engine's per-step hot loop.
 
-    q: (B,H,Dk); caches: (B,CL,KV,D); lengths: (B,) count of valid cache
-    slots per sequence (CL for a warm ring buffer). Slots >= lengths[b]
-    are masked, so the positional-validity invariant of DESIGN.md §1 holds
-    without ever zeroing retired slots. max_len_hint (static, must be
+    q: (B,H,Dk); caches: the stacked head-major slot cache (L,B,KV,CL,D),
+    read where it lies at layer `layer` (a traced scalar: no layer slice
+    is materialized; heads narrower than 128 lanes are read in the
+    transposed layout a TPU stores them in, see `reads_by_columns`);
+    lengths: (B,) count of valid cache slots per sequence (CL for a warm
+    ring buffer). Slots >= lengths[b] are masked, so the
+    positional-validity invariant of DESIGN.md §1 holds without ever
+    zeroing retired slots. max_len_hint (static, must be
     >= max(lengths)) shrinks the KV grid axis itself — blocks beyond the
     hint are never fetched; per-slot `pl.when` skips handle the rest.
     """
     interpret = default_interpret(interpret)
-    return _flash_decode(q, k_cache, v_cache, lengths, scale=scale,
+    return _flash_decode(q, k_cache, v_cache, lengths, layer, scale=scale,
                          block_k=block_k, max_len_hint=max_len_hint,
                          interpret=interpret)
 
@@ -100,8 +104,9 @@ def prefill_attention(q, k_chunk, v_chunk, k_cache, v_cache, offset, *,
     """Chunked-prefill attention: a C-token prompt chunk (Q) against the
     slot cache prefix plus the chunk's own K/V — the admission hot path.
 
-    q: (B,C,H,Dk); k_chunk/v_chunk: (B,C,KV,D); caches: (B,CL,KV,D) in
-    their PRE-chunk state (attend-then-write); offset: scalar absolute
+    q: (B,C,H,Dk); k_chunk/v_chunk: (B,C,KV,D); caches: one layer of the
+    head-major slot cache, (B,KV,CL,D), in their PRE-chunk state
+    (attend-then-write); offset: scalar absolute
     position of the chunk's first token. Cache slots are masked by the
     ring rule p_j = offset-1 - ((offset-1-j) mod CL), valid iff p_j >= 0
     and qp - p_j < CL — which degenerates to j < offset on a full-length

@@ -1,5 +1,6 @@
 """Pallas TPU flash chunked-prefill: a C-token prompt chunk attending to
 the slot cache plus itself — the generation engine's admission hot path.
+It takes one layer of the head-major cache, (B,KV,CL,D).
 
 Chunked-prefill attention has two key sources with different masking:
 
@@ -104,9 +105,9 @@ def prefill_attention(q, k_chunk, v_chunk, k_cache, v_cache, offset, *,
                       scale: float, block_k: int = 128,
                       offset_hint: int | None = None,
                       interpret: bool | None = None):
-    """q: (B,C,H,Dk); k_chunk/v_chunk: (B,C,KV,Dk/Dv); caches:
-    (B,CL,KV,Dk/Dv); offset: scalar int32 absolute position of the chunk's
-    first token. Returns (B,C,H,Dv).
+    """q: (B,C,H,Dk); k_chunk/v_chunk: (B,C,KV,Dk/Dv); caches: one layer
+    of the head-major slot cache, (B,KV,CL,Dk/Dv); offset: scalar int32
+    absolute position of the chunk's first token. Returns (B,C,H,Dv).
 
     The caches must be in their pre-chunk state (attend-then-write, see
     module docstring). Requires C <= CL and CL % block_k == 0. MLA absorbed
@@ -126,7 +127,7 @@ def prefill_attention(q, k_chunk, v_chunk, k_cache, v_cache, offset, *,
     """
     interpret = default_interpret(interpret)
     B, C, H, Dk = q.shape
-    CL, KV = k_cache.shape[1], k_cache.shape[2]
+    KV, CL = k_cache.shape[1], k_cache.shape[2]
     Dv = v_cache.shape[-1]
     rep = H // KV
     block_k = min(block_k, CL)
@@ -140,8 +141,6 @@ def prefill_attention(q, k_chunk, v_chunk, k_cache, v_cache, offset, *,
 
     qr = q.reshape(B, C, KV, rep, Dk).transpose(0, 2, 1, 3, 4)
     qr = qr.reshape(B, KV, rows, Dk)
-    kc = jnp.swapaxes(k_cache, 1, 2)                    # (B,KV,CL,Dk)
-    vc = jnp.swapaxes(v_cache, 1, 2)
     kh = jnp.swapaxes(k_chunk, 1, 2)                    # (B,KV,C,Dk)
     vh = jnp.swapaxes(v_chunk, 1, 2)
     off = jnp.reshape(jnp.asarray(offset, jnp.int32), (1,))
@@ -173,6 +172,6 @@ def prefill_attention(q, k_chunk, v_chunk, k_cache, v_cache, offset, *,
             pltpu.VMEM((rows, Dv), jnp.float32),
         ],
         interpret=interpret,
-    )(off, qr, kc, vc, kh, vh)
+    )(off, qr, k_cache, v_cache, kh, vh)
     out = out.reshape(B, KV, C, rep, Dv).transpose(0, 2, 1, 3, 4)
     return out.reshape(B, C, H, Dv)

@@ -25,7 +25,8 @@ def flash_attention_ref(q, k, v, *, scale: float, causal: bool = True):
 
 
 def flash_decode_ref(q, k_cache, v_cache, lengths, *, scale: float):
-    """Matches kernels.flash_decode (lengths == CL means full ring)."""
+    """Matches kernels.flash_decode on one layer of the head-major cache,
+    (B,KV,CL,D) (lengths == CL means full ring)."""
     return _decode_ref(q, k_cache, v_cache, jnp.asarray(lengths),
                        scale=scale, ring=False)
 
@@ -33,7 +34,8 @@ def flash_decode_ref(q, k_cache, v_cache, lengths, *, scale: float):
 def prefill_attention_ref(q, k_chunk, v_chunk, k_cache, v_cache, offset, *,
                           scale: float):
     """Matches kernels.prefill_attention (two-source chunk-vs-cache
-    attention with ring addressing; caches in their pre-chunk state)."""
+    attention with ring addressing; caches (B,KV,CL,D) in their pre-chunk
+    state)."""
     return _chunk_ref(q, k_chunk, v_chunk, k_cache, v_cache,
                       jnp.asarray(offset, jnp.int32), scale=scale)
 

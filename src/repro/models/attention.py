@@ -3,7 +3,8 @@
 Full-sequence paths use a *blocked* online-softmax implementation (the jnp
 twin of the Pallas flash kernel) so the dry-run memory analysis reflects a
 flash-attention working set instead of a materialized (S, S) score tensor.
-Decode paths read a static-shape ring-buffer KV cache.
+Decode paths read a static-shape ring-buffer KV cache, stacked over layers
+and head-major for GQA (DESIGN.md §1).
 """
 from __future__ import annotations
 
@@ -13,11 +14,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs.base import ModelConfig
+from repro.configs.base import ModelConfig, cache_seq_axis
 from repro.models.layers import ParamDef, apply_rope, rms_norm
 from repro.shardctx import constrain
 
 NEG_INF = -1e30
+KV_SEQ = cache_seq_axis("k")           # ring axis of (L,B,KV,CL,D)
+LATENT_SEQ = cache_seq_axis("c_kv")    # ring axis of (L,B,CL,r)
 
 
 # ---------------------------------------------------------------------------
@@ -159,18 +162,54 @@ def _naive_causal_attention(q, k, v, *, scale, segment_ids=None, window=0):
     return out.reshape(B, S, H, v.shape[-1]).astype(q.dtype)
 
 
-def write_cache(cache, new, index):
-    """Write `new` (B,1,...) into ring-buffer `cache` (B,CL,...) at
-    slot = index % CL. `index` may be a scalar (lockstep decode) or (B,)
-    (continuous-batching engine with per-slot positions)."""
-    CL = cache.shape[1]
-    slot = jnp.mod(index, CL)
+# ---------------------------------------------------------------------------
+# slot cache plumbing (DESIGN.md §1)
+#
+# Slot-cache leaves stay stacked over layers through the whole layer loop:
+# GQA K/V head-major (L,B,KV,CL,D), MLA latents (L,B,CL,r); the ring axis
+# of each is `cache_seq_axis(key)` (KV_SEQ, LATENT_SEQ). Decode writes one
+# row per slot in place and the kernels read the layer where it lies.
+# ---------------------------------------------------------------------------
+
+def cache_layer(cache, layer):
+    """Layer `layer` (a traced scalar) of a stacked (L,...) cache leaf."""
+    return jax.lax.dynamic_index_in_dim(cache, layer, 0, keepdims=False)
+
+
+def put_cache_layer(cache, new, layer):
+    """Write a whole layer back into a stacked (L,...) cache leaf."""
+    return jax.lax.dynamic_update_index_in_dim(
+        cache, new.astype(cache.dtype), layer, 0)
+
+
+def _start(cache, layer, seq_axis, pos, row=0):
+    start = [jnp.asarray(layer, jnp.int32), jnp.asarray(row, jnp.int32)]
+    start += [jnp.int32(0)] * (cache.ndim - 2)
+    start[seq_axis] = jnp.asarray(pos, jnp.int32)
+    return start
+
+
+def write_cache_rows(cache, new, layer, index, seq_axis):
+    """Write each slot's new row into layer `layer` of the stacked slot
+    cache, in place: nothing else in the cache is touched.
+
+    cache: (L,B,...) with the ring on axis `seq_axis`; new: one layer,
+    (B,...), with that axis of size 1; index: scalar (lockstep decode) or
+    (B,) per-slot positions, written at index mod CL. One
+    `dynamic_update_slice` per slot (one for all with a scalar index),
+    which XLA performs in place on the donated buffer. A gather-style
+    scatter (`.at[layer, rows, ..., slot].set`) is avoided on purpose:
+    XLA gives it a layout of its own and relayouts the whole stack after
+    every layer."""
+    slot = jnp.mod(index, cache.shape[seq_axis])
+    new = new.astype(cache.dtype)[None]                       # (1,B,...)
     if jnp.ndim(slot) == 0:
-        start = (0, slot) + (0,) * (cache.ndim - 2)
-        return jax.lax.dynamic_update_slice(cache, new.astype(cache.dtype), start)
-    onehot = (jnp.arange(CL)[None] == slot[:, None]).astype(cache.dtype)
-    onehot = onehot.reshape(onehot.shape + (1,) * (cache.ndim - 2))
-    return cache * (1 - onehot) + new.astype(cache.dtype) * onehot
+        return jax.lax.dynamic_update_slice(
+            cache, new, _start(cache, layer, seq_axis, slot))
+    for b in range(new.shape[1]):
+        cache = jax.lax.dynamic_update_slice(
+            cache, new[:, b:b + 1], _start(cache, layer, seq_axis, slot[b], b))
+    return cache
 
 
 # ---------------------------------------------------------------------------
@@ -193,10 +232,26 @@ def paged_gather(pool, block_tables):
     return v.reshape((v.shape[0], v.shape[1] * v.shape[2]) + v.shape[3:])
 
 
+def settled(*views):
+    """Materialize attention inputs before jnp attention reads them. A slot
+    layer slice and a gathered page view then feed the same code whatever
+    XLA would fuse into the dots, which keeps the paged engine bitwise
+    equal to the slot engine."""
+    return jax.lax.optimization_barrier(views)
+
+
+def paged_gather_heads(pool, block_tables):
+    """K/V pool (NP,PS,KV,D) -> per-slot view (B,KV,CL,D): one layer in
+    the slot cache's head-major layout, so the attention that reads it is
+    the slot path's own."""
+    return jnp.swapaxes(paged_gather(pool, block_tables), 1, 2)
+
+
 def write_cache_paged(pool, new, index, block_tables):
-    """Paged twin of `write_cache`: write `new` (B,1,...) at ring position
-    index mod CL of each row. Inactive rows' block-table entries point at
-    the trash page, which absorbs their static-shape stale writes."""
+    """Paged twin of `write_cache_rows`: write `new` (B,1,...) at ring
+    position index mod CL of each row. Inactive rows' block-table entries
+    point at the trash page, which absorbs their static-shape stale
+    writes."""
     B = new.shape[0]
     PS, NB = pool.shape[1], block_tables.shape[1]
     CL = NB * PS
@@ -245,15 +300,19 @@ def uses_flash_decode(cfg: ModelConfig, cache_len: int) -> bool:
 
 
 def decode_attention(q, k_cache, v_cache, cache_index, *, scale, ring: bool):
-    """q: (B,H,Dk); caches: (B,CL,KV,D). One-token flash-decode reference.
+    """q: (B,H,Dk); caches: one head-major layer (B,KV,CL,D). One-token
+    flash-decode reference, the jnp twin of the kernel. It reads the layer
+    as a (B,CL,KV,D) view: XLA's CPU backend refuses some bf16 dots with
+    an f32 result in the head-major form.
 
     ring=True: the cache is a full ring buffer (all slots valid).
     ring=False: slots >= cache_index are masked out. cache_index may be a
     scalar or per-slot (B,).
     """
     B, H, Dk = q.shape
-    CL, KV = k_cache.shape[1], k_cache.shape[2]
+    KV, CL = k_cache.shape[1], k_cache.shape[2]
     rep = H // KV
+    k_cache, v_cache = jnp.swapaxes(k_cache, 1, 2), jnp.swapaxes(v_cache, 1, 2)
     qr = q.reshape(B, KV, rep, Dk)
     s = jnp.einsum("bgrd,bkgd->bgrk", qr, k_cache,
                    preferred_element_type=jnp.float32) * scale
@@ -313,11 +372,27 @@ def gqa_forward(p, x, positions, cfg: ModelConfig, segment_ids=None,
     return y
 
 
-def gqa_decode(p, x, positions, cache_k, cache_v, cache_index, cfg: ModelConfig,
-               ring: bool, kv_len_hint=None, block_tables=None,
-               paged_kernel: bool = False):
-    """One-token decode. x: (B,1,d); caches (B,CL,KV,Dk), or page pools
-    (NP,PS,KV,Dk) when `block_tables` (B,NB) is given. Returns y, new caches.
+def _decode_lengths(B, CL, cache_index, ring: bool):
+    """Valid cache slots per row for the decode kernels, clamped to CL:
+    once a ring cache has wrapped (cache_index >= CL) every slot is valid,
+    and the clamp keeps the early exit tight."""
+    if ring:
+        return jnp.full((B,), CL, jnp.int32)
+    return jnp.broadcast_to(jnp.minimum(
+        jnp.asarray(cache_index + 1, jnp.int32), CL), (B,))
+
+
+def gqa_decode(p, x, positions, cache_k, cache_v, layer, cache_index,
+               cfg: ModelConfig, ring: bool, kv_len_hint=None,
+               block_tables=None, paged_kernel: bool = False):
+    """One-token decode of layer `layer`. x: (B,1,d); caches: the stacked
+    head-major slot cache (L,B,KV,CL,Dk), or stacked page pools
+    (L,NP,PS,KV,Dk) when `block_tables` (B,NB) is given. Returns y and the
+    new stacked caches.
+
+    Slot path: each slot's new K/V row is written in place
+    (`write_cache_rows`) and the attention reads the layer where it lies
+    (`flash_decode` takes the stack and the layer index).
 
     kv_len_hint: optional static upper bound on the valid cache length
     across the batch (host-mirrored by the engine); shrinks the flash-decode
@@ -334,43 +409,45 @@ def gqa_decode(p, x, positions, cache_k, cache_v, cache_index, cfg: ModelConfig,
     q, k = _maybe_qk_norm(cfg, p, q, k)
     q = apply_rope(q, positions, cfg.rope_theta)
     k = apply_rope(k, positions, cfg.rope_theta)
+    scale = 1.0 / np.sqrt(cfg.d_head)
     if block_tables is None:
-        CL = cache_k.shape[1]
-        cache_k = write_cache(cache_k, k, cache_index)
-        cache_v = write_cache(cache_v, v, cache_index)
-        view_k, view_v = cache_k, cache_v
+        CL = cache_k.shape[3]
+        cache_k = write_cache_rows(cache_k, jnp.swapaxes(k, 1, 2), layer,
+                                   cache_index, KV_SEQ)
+        cache_v = write_cache_rows(cache_v, jnp.swapaxes(v, 1, 2), layer,
+                                   cache_index, KV_SEQ)
+        view_k, view_v, at = cache_k, cache_v, layer
     else:
-        CL = block_tables.shape[1] * cache_k.shape[1]
-        cache_k = write_cache_paged(cache_k, k, cache_index, block_tables)
-        cache_v = write_cache_paged(cache_v, v, cache_index, block_tables)
+        pool_k = write_cache_paged(cache_layer(cache_k, layer), k,
+                                   cache_index, block_tables)
+        pool_v = write_cache_paged(cache_layer(cache_v, layer), v,
+                                   cache_index, block_tables)
+        cache_k = put_cache_layer(cache_k, pool_k, layer)
+        cache_v = put_cache_layer(cache_v, pool_v, layer)
+        CL = block_tables.shape[1] * pool_k.shape[1]
         if paged_kernel and uses_flash_decode(cfg, CL):
             from repro.kernels import ops as kops
-            lengths = jnp.full((B,), CL, jnp.int32) if ring else \
-                jnp.broadcast_to(jnp.minimum(
-                    jnp.asarray(cache_index + 1, jnp.int32), CL), (B,))
             y = kops.flash_decode_paged(
-                q[:, 0], cache_k, cache_v, block_tables, lengths,
-                scale=1.0 / np.sqrt(cfg.d_head), max_len_hint=kv_len_hint,
-                interpret=cfg.pallas_interpret)
+                q[:, 0], pool_k, pool_v, block_tables,
+                _decode_lengths(B, CL, cache_index, ring), scale=scale,
+                max_len_hint=kv_len_hint, interpret=cfg.pallas_interpret)
             y = jnp.einsum("bhk,hkd->bd", y, p["wo"])[:, None]
             return y, (cache_k, cache_v)
-        view_k = paged_gather(cache_k, block_tables)
-        view_v = paged_gather(cache_v, block_tables)
+        view_k = paged_gather_heads(pool_k, block_tables)[None]
+        view_v = paged_gather_heads(pool_v, block_tables)[None]
+        at = 0
     if uses_flash_decode(cfg, CL):
         from repro.kernels import ops as kops
-        # clamp to CL: once a ring cache has wrapped (cache_index >= CL)
-        # every slot is valid, and the clamp keeps the early-exit tight
-        lengths = jnp.full((B,), CL, jnp.int32) if ring else \
-            jnp.broadcast_to(jnp.minimum(
-                jnp.asarray(cache_index + 1, jnp.int32), CL), (B,))
-        y = kops.flash_decode(q[:, 0], view_k, view_v, lengths,
-                              scale=1.0 / np.sqrt(cfg.d_head),
-                              block_k=decode_block_k(CL),
+        y = kops.flash_decode(q[:, 0], view_k, view_v,
+                              _decode_lengths(B, CL, cache_index, ring), at,
+                              scale=scale, block_k=decode_block_k(CL),
                               max_len_hint=kv_len_hint,
                               interpret=cfg.pallas_interpret)
     else:
+        view_k, view_v = settled(cache_layer(view_k, at),
+                                 cache_layer(view_v, at))
         y = decode_attention(q[:, 0], view_k, view_v, cache_index + 1,
-                             scale=1.0 / np.sqrt(cfg.d_head), ring=ring)
+                             scale=scale, ring=ring)
     y = jnp.einsum("bhk,hkd->bd", y, p["wo"])[:, None]
     return y, (cache_k, cache_v)
 
@@ -411,13 +488,15 @@ def mla_forward(p, x, positions, cfg: ModelConfig, segment_ids=None,
     return y
 
 
-def mla_decode(p, x, positions, cache_ckv, cache_krope, cache_index,
+def mla_decode(p, x, positions, cache_ckv, cache_krope, layer, cache_index,
                cfg: ModelConfig, ring: bool, block_tables=None,
                paged_kernel: bool = False):
-    """Absorbed MLA decode: scores in latent space, cache stays compressed.
-    With `block_tables`, the latent caches are page pools (NP,PS,r) —
-    write the token's latent into its page, gather the per-slot view, and
-    run the identical absorbed attention (bit-equal to the slot cache)."""
+    """Absorbed MLA decode of layer `layer`: scores in latent space, cache
+    stays compressed. Latent caches are stacked (L,B,CL,r); each slot's
+    new latent row is written in place. With `block_tables`, they are
+    stacked page pools (L,NP,PS,r) — write the token's latent into its
+    page, gather the per-slot view, and run the identical absorbed
+    attention (bit-equal to the slot cache)."""
     del paged_kernel  # MLA decodes through the absorbed jnp path
     B = x.shape[0]
     nope, rope = cfg.qk_nope_dim, cfg.qk_rope_dim
@@ -431,18 +510,23 @@ def mla_decode(p, x, positions, cache_ckv, cache_krope, cache_index,
     c_kv = rms_norm(kv[..., :cfg.kv_lora_rank], p["kv_norm"], cfg.norm_eps)
     k_rope = apply_rope(kv[..., cfg.kv_lora_rank:], positions, cfg.rope_theta)
     if block_tables is None:
-        CL = cache_ckv.shape[1]
-        cache_ckv = write_cache(cache_ckv, c_kv, cache_index)
-        cache_krope = write_cache(cache_krope, k_rope, cache_index)
-        view_ckv, view_krope = cache_ckv, cache_krope
+        cache_ckv = write_cache_rows(cache_ckv, c_kv, layer, cache_index,
+                                     LATENT_SEQ)
+        cache_krope = write_cache_rows(cache_krope, k_rope, layer,
+                                       cache_index, LATENT_SEQ)
+        view_ckv = cache_layer(cache_ckv, layer)
+        view_krope = cache_layer(cache_krope, layer)
     else:
-        CL = block_tables.shape[1] * cache_ckv.shape[1]
-        cache_ckv = write_cache_paged(cache_ckv, c_kv, cache_index,
-                                      block_tables)
-        cache_krope = write_cache_paged(cache_krope, k_rope, cache_index,
-                                        block_tables)
-        view_ckv = paged_gather(cache_ckv, block_tables)
-        view_krope = paged_gather(cache_krope, block_tables)
+        pool_ckv = write_cache_paged(cache_layer(cache_ckv, layer), c_kv,
+                                     cache_index, block_tables)
+        pool_krope = write_cache_paged(cache_layer(cache_krope, layer),
+                                       k_rope, cache_index, block_tables)
+        cache_ckv = put_cache_layer(cache_ckv, pool_ckv, layer)
+        cache_krope = put_cache_layer(cache_krope, pool_krope, layer)
+        view_ckv = paged_gather(pool_ckv, block_tables)
+        view_krope = paged_gather(pool_krope, block_tables)
+    CL = view_ckv.shape[1]
+    view_ckv, view_krope = settled(view_ckv, view_krope)
 
     # absorb W_uk into q: (B,H,nope) x (r,H,nope) -> (B,H,r)
     q_latent = jnp.einsum("bhk,rhk->bhr", q_nope, p["wk_b"])
@@ -467,8 +551,10 @@ def mla_decode(p, x, positions, cache_ckv, cache_krope, cache_index,
 # chunked prefill: a C-token query block against the slot cache + itself
 # ---------------------------------------------------------------------------
 
-def write_cache_chunk(cache, new, offset, write_mask=None):
-    """Write `new` (B,C,...) into `cache` (B,CL,...) at [offset, offset+C).
+def write_cache_chunk(cache, new, layer, offset, seq_axis, write_mask=None):
+    """Write `new` — one layer in the cache's layout, (B,...), with C rows
+    on the ring axis — into layer `layer` of the stacked slot cache
+    (L,B,...) at [offset, offset+C) of axis `seq_axis`, in place.
 
     write_mask may be (B,) — only admitted rows may be touched (the others
     hold live K/V of in-progress sequences) — or (B,C) to additionally
@@ -478,31 +564,43 @@ def write_cache_chunk(cache, new, offset, write_mask=None):
     decode masking treats as valid). The caller passes `offset` already
     reduced mod CL; chunk size divides CL so the slice never shifts.
     """
-    C = new.shape[1]
-    merged = new.astype(cache.dtype)
+    merged = new.astype(cache.dtype)[None]                    # (1,B,...)
+    start = _start(cache, layer, seq_axis, offset)
     if write_mask is not None:
-        old = jax.lax.dynamic_slice_in_dim(cache, offset, C, axis=1)
-        shape = write_mask.shape + (1,) * (cache.ndim - write_mask.ndim)
+        old = jax.lax.dynamic_slice(cache, start, merged.shape)
+        shape = [1] * cache.ndim
+        shape[1] = write_mask.shape[0]
+        if write_mask.ndim == 2:
+            shape[seq_axis] = write_mask.shape[1]
         merged = jnp.where(write_mask.reshape(shape), merged, old)
-    return jax.lax.dynamic_update_slice_in_dim(cache, merged, offset, axis=1)
+    return jax.lax.dynamic_update_slice(cache, merged, start)
 
 
 def chunk_attention(q, k_chunk, v_chunk, k_cache, v_cache, offset, *, scale):
     """Two-source chunked-prefill attention (jnp twin of the Pallas
     `kernels.prefill_attention` kernel — see its docstring for the mask
     derivation). q: (B,C,H,Dk); k_chunk/v_chunk: (B,C,KV,D); caches:
-    (B,CL,KV,D) in their PRE-chunk state; offset: scalar absolute position
-    of the chunk's first token.
+    one head-major layer (B,KV,CL,D) in their PRE-chunk state, read as a
+    (B,CL,KV,D) view like `decode_attention`'s; offset: scalar absolute
+    position of the chunk's first token.
 
     Query i (absolute position qp = offset+i) attends to (1) cache slots j
     holding absolute position p_j = offset-1 - ((offset-1-j) mod CL) with
     p_j >= 0 and qp - p_j < CL (ring addressing; degenerates to j < offset
-    on a full-length cache), and (2) the chunk's own keys causally."""
+    on a full-length cache), and (2) the chunk's own keys causally.
+
+    The einsums take f32 operands: exact for bf16 inputs (the products and
+    the f32 sums of a bf16 dot), and XLA's CPU backend refuses some bf16
+    dots with an f32 result."""
     B, C, H, Dk = q.shape
-    CL, KV = k_cache.shape[1], k_cache.shape[2]
+    KV, CL = k_cache.shape[1], k_cache.shape[2]
     Dv = v_cache.shape[-1]
     rep = H // KV
-    qr = q.reshape(B, C, KV, rep, Dk)
+    f32, vdt, cdt = jnp.float32, v_cache.dtype, v_chunk.dtype
+    k_cache = jnp.swapaxes(k_cache, 1, 2).astype(f32)
+    v_cache = jnp.swapaxes(v_cache, 1, 2).astype(f32)
+    k_chunk, v_chunk = k_chunk.astype(f32), v_chunk.astype(f32)
+    qr = q.reshape(B, C, KV, rep, Dk).astype(f32)
     qp = offset + jnp.arange(C)                                   # (C,)
     j = jnp.arange(CL)
     p_j = (offset - 1) - jnp.mod(offset - 1 - j, CL)              # (CL,)
@@ -515,9 +613,9 @@ def chunk_attention(q, k_chunk, v_chunk, k_cache, v_cache, offset, *, scale):
     causal = jnp.arange(C)[:, None] >= jnp.arange(C)[None, :]     # (C,C)
     s_chunk = jnp.where(causal[None, None, None], s_chunk, NEG_INF)
     p = jax.nn.softmax(jnp.concatenate([s_cache, s_chunk], axis=-1), axis=-1)
-    out = jnp.einsum("bgrqk,bkgd->bqgrd", p[..., :CL].astype(v_cache.dtype),
+    out = jnp.einsum("bgrqk,bkgd->bqgrd", p[..., :CL].astype(vdt).astype(f32),
                      v_cache, preferred_element_type=jnp.float32)
-    out += jnp.einsum("bgrqk,bkgd->bqgrd", p[..., CL:].astype(v_chunk.dtype),
+    out += jnp.einsum("bgrqk,bkgd->bqgrd", p[..., CL:].astype(cdt).astype(f32),
                       v_chunk, preferred_element_type=jnp.float32)
     return out.reshape(B, C, H, Dv).astype(q.dtype)
 
@@ -537,10 +635,11 @@ def _chunk_attention_any(q, k_chunk, v_chunk, k_cache, v_cache, offset,
                          cfg: ModelConfig, scale: float,
                          offset_hint: Optional[int] = None):
     """Route chunk-vs-cache attention through the Pallas prefill kernel
-    when shapes fit, else the jnp twin. offset_hint (static, >=
-    min(offset, CL)) shrinks the kernel's cache-block grid — far cache
-    blocks are never launched for early chunks."""
-    C, CL = q.shape[1], k_cache.shape[1]
+    when shapes fit, else the jnp twin. Caches: one head-major layer
+    (B,KV,CL,D). offset_hint (static, >= min(offset, CL)) shrinks the
+    kernel's cache-block grid — far cache blocks are never launched for
+    early chunks."""
+    C, CL = q.shape[1], k_cache.shape[2]
     if _use_prefill_kernel(cfg, C, CL):
         from repro.kernels import ops as kops
         return kops.prefill_attention(q, k_chunk, v_chunk, k_cache, v_cache,
@@ -548,51 +647,55 @@ def _chunk_attention_any(q, k_chunk, v_chunk, k_cache, v_cache, offset,
                                       block_k=prefill_block_k(CL),
                                       offset_hint=offset_hint,
                                       interpret=cfg.pallas_interpret)
+    k_cache, v_cache = settled(k_cache, v_cache)
     return chunk_attention(q, k_chunk, v_chunk, k_cache, v_cache, offset,
                            scale=scale)
 
 
-def gqa_prefill_chunk(p, x, positions, cache_k, cache_v, offset, write_mask,
-                      cfg: ModelConfig, offset_hint: Optional[int] = None,
-                      block_tables=None):
-    """One GQA layer over a C-token prompt chunk. x: (B,C,d). Attends the
-    chunk against the cache prefix plus itself (attend-then-write: on a
-    ring cache the chunk's writes evict exactly the slots leaving the
-    window), then writes the chunk's K/V at [offset mod CL, ...) masked by
-    write_mask (B,) or (B,C). With `block_tables` the caches are page
-    pools: attend against the gathered view, write into pages (the engine
-    keeps chunk | page_size, so the chunk lands in one block).
-    Returns y (B,C,d), (cache_k, cache_v)."""
+def gqa_prefill_chunk(p, x, positions, cache_k, cache_v, layer, offset,
+                      write_mask, cfg: ModelConfig,
+                      offset_hint: Optional[int] = None, block_tables=None):
+    """Layer `layer` of GQA over a C-token prompt chunk. x: (B,C,d).
+    Attends the chunk against the cache prefix plus itself
+    (attend-then-write: on a ring cache the chunk's writes evict exactly
+    the slots leaving the window), then writes the chunk's K/V at
+    [offset mod CL, ...) of the stacked head-major cache (L,B,KV,CL,D),
+    masked by write_mask (B,) or (B,C). With `block_tables` the caches are
+    stacked page pools (L,NP,PS,KV,D): attend against the gathered view,
+    write into pages (the engine keeps chunk | page_size, so the chunk
+    lands in one block). Returns y (B,C,d), (cache_k, cache_v)."""
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
     k = jnp.einsum("bsd,dhk->bshk", x, p["wk"])
     v = jnp.einsum("bsd,dhk->bshk", x, p["wv"])
     q, k = _maybe_qk_norm(cfg, p, q, k)
     q = apply_rope(q, positions, cfg.rope_theta)
     k = apply_rope(k, positions, cfg.rope_theta)
+    pool_k, pool_v = cache_layer(cache_k, layer), cache_layer(cache_v, layer)
     if block_tables is None:
-        view_k, view_v = cache_k, cache_v
-        CL = cache_k.shape[1]
+        view_k, view_v = pool_k, pool_v
     else:
-        view_k = paged_gather(cache_k, block_tables)
-        view_v = paged_gather(cache_v, block_tables)
-        CL = view_k.shape[1]
+        view_k = paged_gather_heads(pool_k, block_tables)
+        view_v = paged_gather_heads(pool_v, block_tables)
+    CL = view_k.shape[2]
     y = _chunk_attention_any(q, k, v, view_k, view_v, offset, cfg,
                              1.0 / np.sqrt(cfg.d_head),
                              offset_hint=offset_hint)
     off_w = jnp.mod(offset, CL)
     if block_tables is None:
-        cache_k = write_cache_chunk(cache_k, k, off_w, write_mask)
-        cache_v = write_cache_chunk(cache_v, v, off_w, write_mask)
+        cache_k = write_cache_chunk(cache_k, jnp.swapaxes(k, 1, 2), layer,
+                                    off_w, KV_SEQ, write_mask)
+        cache_v = write_cache_chunk(cache_v, jnp.swapaxes(v, 1, 2), layer,
+                                    off_w, KV_SEQ, write_mask)
     else:
-        cache_k = write_cache_chunk_paged(cache_k, k, off_w, write_mask,
-                                          block_tables)
-        cache_v = write_cache_chunk_paged(cache_v, v, off_w, write_mask,
-                                          block_tables)
+        cache_k = put_cache_layer(cache_k, write_cache_chunk_paged(
+            pool_k, k, off_w, write_mask, block_tables), layer)
+        cache_v = put_cache_layer(cache_v, write_cache_chunk_paged(
+            pool_v, v, off_w, write_mask, block_tables), layer)
     y = jnp.einsum("bshk,hkd->bsd", y, p["wo"])
     return y, (cache_k, cache_v)
 
 
-def mla_prefill_chunk(p, x, positions, cache_ckv, cache_krope, offset,
+def mla_prefill_chunk(p, x, positions, cache_ckv, cache_krope, layer, offset,
                       write_mask, cfg: ModelConfig,
                       offset_hint: Optional[int] = None,
                       block_tables=None):
@@ -601,15 +704,17 @@ def mla_prefill_chunk(p, x, positions, cache_ckv, cache_krope, offset,
     Routed through the shared prefill-attention primitive by treating the
     latent as a single KV head with the rope part concatenated onto the key
     dim (score = q_latent·c_kv + q_rope·k_rope) and the latent itself as
-    the value. Returns y (B,C,d), (cache_ckv, cache_krope)."""
+    the value. Latent caches are stacked (L,B,CL,r), or stacked page pools
+    with `block_tables`. Returns y (B,C,d), (cache_ckv, cache_krope)."""
     B, C, _ = x.shape
+    pool_ckv = cache_layer(cache_ckv, layer)
+    pool_krope = cache_layer(cache_krope, layer)
     if block_tables is None:
-        CL = cache_ckv.shape[1]
-        view_ckv, view_krope = cache_ckv, cache_krope
+        view_ckv, view_krope = pool_ckv, pool_krope
     else:
-        view_ckv = paged_gather(cache_ckv, block_tables)
-        view_krope = paged_gather(cache_krope, block_tables)
-        CL = view_ckv.shape[1]
+        view_ckv = paged_gather(pool_ckv, block_tables)
+        view_krope = paged_gather(pool_krope, block_tables)
+    CL = view_ckv.shape[1]
     nope, rope = cfg.qk_nope_dim, cfg.qk_rope_dim
     q = jnp.einsum("bsd,dr->bsr", x, p["wq_a"])
     q = rms_norm(q, p["q_norm"], cfg.norm_eps)
@@ -625,21 +730,23 @@ def mla_prefill_chunk(p, x, positions, cache_ckv, cache_krope, offset,
     q_latent = jnp.einsum("bqhk,rhk->bqhr", q_nope, p["wk_b"])
     q_cat = jnp.concatenate([q_latent, q_rope], axis=-1)     # (B,C,H,r+rope)
     kh_cat = jnp.concatenate([c_kv, k_rope], axis=-1)[:, :, None]
-    kc_cat = jnp.concatenate([view_ckv, view_krope], axis=-1)[:, :, None]
+    kc_cat = jnp.concatenate([view_ckv, view_krope], axis=-1)[:, None]
     o_latent = _chunk_attention_any(
-        q_cat, kh_cat, c_kv[:, :, None], kc_cat, view_ckv[:, :, None],
+        q_cat, kh_cat, c_kv[:, :, None], kc_cat, view_ckv[:, None],
         offset, cfg, 1.0 / np.sqrt(nope + rope),
         offset_hint=offset_hint)                             # (B,C,H,r)
 
     off_w = jnp.mod(offset, CL)
     if block_tables is None:
-        cache_ckv = write_cache_chunk(cache_ckv, c_kv, off_w, write_mask)
-        cache_krope = write_cache_chunk(cache_krope, k_rope, off_w, write_mask)
+        cache_ckv = write_cache_chunk(cache_ckv, c_kv, layer, off_w,
+                                      LATENT_SEQ, write_mask)
+        cache_krope = write_cache_chunk(cache_krope, k_rope, layer, off_w,
+                                        LATENT_SEQ, write_mask)
     else:
-        cache_ckv = write_cache_chunk_paged(cache_ckv, c_kv, off_w,
-                                            write_mask, block_tables)
-        cache_krope = write_cache_chunk_paged(cache_krope, k_rope, off_w,
-                                              write_mask, block_tables)
+        cache_ckv = put_cache_layer(cache_ckv, write_cache_chunk_paged(
+            pool_ckv, c_kv, off_w, write_mask, block_tables), layer)
+        cache_krope = put_cache_layer(cache_krope, write_cache_chunk_paged(
+            pool_krope, k_rope, off_w, write_mask, block_tables), layer)
     o = jnp.einsum("bqhr,rhk->bqhk", o_latent.astype(x.dtype), p["wv_b"])
     y = jnp.einsum("bqhk,hkd->bqd", o, p["wo"])
     return y, (cache_ckv, cache_krope)

@@ -109,7 +109,8 @@ def _layer_forward(cfg: ModelConfig, kind: str, h, lp, positions, segment_ids,
         mix = 0.5 * (rms_norm(a, lp["hyb_norm_a"], cfg.norm_eps)
                      + rms_norm(s, lp["hyb_norm_s"], cfg.norm_eps))
         h = h + mix
-        kv = {"k": kv_a[0], "v": kv_a[1], "conv": st[0], "ssd": st[1]}
+        kv = {"k": jnp.swapaxes(kv_a[0], 1, 2),
+              "v": jnp.swapaxes(kv_a[1], 1, 2), "conv": st[0], "ssd": st[1]}
     elif cfg.arch_type == "ssm":
         s, st = ssm_mod.ssm_forward(lp["ssm"], x, cfg, return_state=True)
         h = h + s
@@ -120,8 +121,9 @@ def _layer_forward(cfg: ModelConfig, kind: str, h, lp, positions, segment_ids,
         h = h + a
         if cfg.use_mla:
             kv = {"c_kv": kv_a[0], "k_rope": kv_a[1]}
-        else:
-            kv = {"k": kv_a[0], "v": kv_a[1]}
+        else:  # the slot cache's head-major layout (B,KV,S,D)
+            kv = {"k": jnp.swapaxes(kv_a[0], 1, 2),
+                  "v": jnp.swapaxes(kv_a[1], 1, 2)}
     h = constrain(h, ("batch", "seq", "embed"))
 
     if kind == "dense":
@@ -392,12 +394,46 @@ def _cached_layer_step(cfg: ModelConfig, kind: str, h, lp, attn_fn, ssm_fn):
     return h, ncs
 
 
+def _ssm_layer(cache, layer, fn):
+    """Run `fn(conv, ssd) -> (y, (conv, ssd))` on layer `layer` of the
+    stacked recurrent state; returns y and the state with the layer
+    written back."""
+    y, (conv, ssd) = fn(attn.cache_layer(cache["conv"], layer),
+                        attn.cache_layer(cache["ssd"], layer))
+    return y, {"conv": attn.put_cache_layer(cache["conv"], conv, layer),
+               "ssd": attn.put_cache_layer(cache["ssd"], ssd, layer)}
+
+
+def _scan_layers(cfg: ModelConfig, params, h, cache, body):
+    """Run every layer group's scan with the whole stacked (L,...) cache as
+    the carry: `body(kind, h, cache, lp, layer) -> (h, cache)` reads and
+    writes its layer of the stack (layer is the traced absolute index), so
+    no layer slice goes in as a scan input or comes out as an output."""
+    offset = 0
+    for gi, (kind, count) in enumerate(layer_groups(cfg)):
+        def scan_body(carry, inp, _kind=kind):
+            lp, layer = inp
+            return body(_kind, *carry, lp, layer), None
+
+        layers = jnp.arange(offset, offset + count, dtype=jnp.int32)
+        (h, cache), _ = jax.lax.scan(scan_body, (h, cache),
+                                     (params["groups"][gi], layers),
+                                     unroll=True if cfg.scan_unroll else 1)
+        offset += count
+    return h, cache
+
+
 def decode_step(params, tokens, positions, cache, cache_index,
                 cfg: ModelConfig, *, ring: Optional[bool] = None,
                 kv_len_hint: Optional[int] = None, block_tables=None,
                 paged_kernel: bool = False):
     """tokens: (B,1); cache: stacked (L,...) tree; cache_index: scalar or (B,).
     Returns (logits (B,1,V), values (B,1)?, new_cache).
+
+    The stacked cache is the layer loop's carry: each layer writes one new
+    row per slot into it in place and the attention reads its layer where
+    it lies (DESIGN.md §1); with the cache donated, the step touches
+    nothing else of it.
 
     kv_len_hint: optional static upper bound on the valid cache length
     across the batch; forwarded to the flash-decode kernel to shrink its
@@ -410,7 +446,6 @@ def decode_step(params, tokens, positions, cache, cache_index,
     keep the slot layout either way. paged_kernel routes GQA decode
     through the scalar-prefetch paged kernel instead of gather-then-
     flash_decode."""
-    B = tokens.shape[0]
     if ring is None:
         # ring addressing applies only to attention caches, and is on
         # exactly when the sliding-window variant allocated a ring buffer
@@ -420,43 +455,28 @@ def decode_step(params, tokens, positions, cache, cache_index,
     h = jnp.take(params["embed"], tokens, axis=0)
     h = constrain(h, ("batch", "seq", "embed"))
 
-    offset = 0
-    new_cache = {k: [] for k in cache}
-    for gi, (kind, count) in enumerate(layer_groups(cfg)):
-        gp = params["groups"][gi]
-        cache_slice = {k: jax.lax.slice_in_dim(v, offset, offset + count, axis=0)
-                       for k, v in cache.items()}
+    def body(kind, h, cache, lp, layer):
+        def attn_fn(pa, x):
+            if cfg.use_mla:
+                a, (nck, nkr) = attn.mla_decode(
+                    pa, x, positions, cache["c_kv"], cache["k_rope"], layer,
+                    cache_index, cfg, ring, block_tables=block_tables,
+                    paged_kernel=paged_kernel)
+                return a, {"c_kv": nck, "k_rope": nkr}
+            a, (nk, nv) = attn.gqa_decode(
+                pa, x, positions, cache["k"], cache["v"], layer, cache_index,
+                cfg, ring, kv_len_hint=kv_len_hint,
+                block_tables=block_tables, paged_kernel=paged_kernel)
+            return a, {"k": nk, "v": nv}
 
-        def scan_body(h, inp, _kind=kind):
-            lp, cs = inp
+        def ssm_fn(ps, x):
+            return _ssm_layer(cache, layer, functools.partial(
+                ssm_mod.ssm_decode, ps, x, cfg=cfg))
 
-            def attn_fn(pa, x):
-                if cfg.use_mla:
-                    a, (nck, nkr) = attn.mla_decode(
-                        pa, x, positions, cs["c_kv"], cs["k_rope"],
-                        cache_index, cfg, ring, block_tables=block_tables,
-                        paged_kernel=paged_kernel)
-                    return a, {"c_kv": nck, "k_rope": nkr}
-                a, (nk, nv) = attn.gqa_decode(
-                    pa, x, positions, cs["k"], cs["v"], cache_index,
-                    cfg, ring, kv_len_hint=kv_len_hint,
-                    block_tables=block_tables, paged_kernel=paged_kernel)
-                return a, {"k": nk, "v": nv}
+        h, ncs = _cached_layer_step(cfg, kind, h, lp, attn_fn, ssm_fn)
+        return h, {**cache, **ncs}
 
-            def ssm_fn(ps, x):
-                s, (ncv, nss) = ssm_mod.ssm_decode(
-                    ps, x, cs["conv"], cs["ssd"], cfg)
-                return s, {"conv": ncv, "ssd": nss}
-
-            return _cached_layer_step(cfg, _kind, h, lp, attn_fn, ssm_fn)
-
-        h, kvs = jax.lax.scan(scan_body, h, (gp, cache_slice),
-                              unroll=True if cfg.scan_unroll else 1)
-        for k in cache:
-            new_cache[k].append(kvs[k])
-        offset += count
-
-    new_cache = {k: jnp.concatenate(v, axis=0) for k, v in new_cache.items()}
+    h, new_cache = _scan_layers(cfg, params, h, dict(cache), body)
     h = rms_norm(h, params["final_norm"], cfg.norm_eps)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     logits = jnp.einsum("bsd,dv->bsv", h, head)
@@ -484,7 +504,7 @@ def prefill_chunk(params, tokens, prompt_len, offset, admit_mask, cache,
 
     Runs `chunk` prompt tokens (positions [offset, offset+chunk)) of every
     slot through the full layer stack and writes their K/V (MLA latent /
-    SSM state) straight into the slot cache via dynamic_update_slice, so
+    SSM state) straight into the stacked slot cache in place, so
     admitting a prompt of length P costs ceil((P-1)/chunk) batched forwards
     instead of P-1 one-token decode steps. Chunk attention runs through the
     Pallas prefill kernel (`kernels.prefill_attention`) when shapes fit,
@@ -540,46 +560,32 @@ def prefill_chunk(params, tokens, prompt_len, offset, admit_mask, cache,
     h = jnp.take(params["embed"], toks, axis=0)
     h = constrain(h, ("batch", "seq", "embed"))
 
-    lg = layer_groups(cfg)
-    off_layers = 0
-    new_cache = {k: [] for k in cache}
-    for gi, (kind, count) in enumerate(lg):
-        gp = params["groups"][gi]
-        cache_slice = {k: jax.lax.slice_in_dim(v, off_layers,
-                                               off_layers + count, axis=0)
-                       for k, v in cache.items()}
-
-        def scan_body(h, inp, _kind=kind):
-            lp, cs = inp
-
-            def attn_fn(pa, x):
-                if cfg.use_mla:
-                    a, (nck, nkr) = attn.mla_prefill_chunk(
-                        pa, x, positions, cs["c_kv"], cs["k_rope"],
-                        offset, kv_write_mask, cfg, offset_hint=offset_hint,
-                        block_tables=block_tables)
-                    return a, {"c_kv": nck, "k_rope": nkr}
-                a, (nk, nv) = attn.gqa_prefill_chunk(
-                    pa, x, positions, cs["k"], cs["v"], offset,
-                    kv_write_mask, cfg, offset_hint=offset_hint,
+    def body(kind, h, cache, lp, layer):
+        def attn_fn(pa, x):
+            if cfg.use_mla:
+                a, (nck, nkr) = attn.mla_prefill_chunk(
+                    pa, x, positions, cache["c_kv"], cache["k_rope"], layer,
+                    offset, kv_write_mask, cfg, offset_hint=offset_hint,
                     block_tables=block_tables)
-                return a, {"k": nk, "v": nv}
+                return a, {"c_kv": nck, "k_rope": nkr}
+            a, (nk, nv) = attn.gqa_prefill_chunk(
+                pa, x, positions, cache["k"], cache["v"], layer, offset,
+                kv_write_mask, cfg, offset_hint=offset_hint,
+                block_tables=block_tables)
+            return a, {"k": nk, "v": nv}
 
-            def ssm_fn(ps, x):
+        def ssm_fn(ps, x):
+            def run(conv, ssd):
                 s, (ncv, nss) = ssm_mod.ssm_forward(
-                    ps, x, cfg, return_state=True,
-                    initial_state=(cs["conv"], cs["ssd"]),
+                    ps, x, cfg, return_state=True, initial_state=(conv, ssd),
                     token_mask=tok_mask)
                 # only admitted rows may advance recurrent state
-                return s, {"conv": _merge_state(ncv, cs["conv"], admit_mask),
-                           "ssd": _merge_state(nss, cs["ssd"], admit_mask)}
+                return s, (_merge_state(ncv, conv, admit_mask),
+                           _merge_state(nss, ssd, admit_mask))
 
-            return _cached_layer_step(cfg, _kind, h, lp, attn_fn, ssm_fn)
+            return _ssm_layer(cache, layer, run)
 
-        h, kvs = jax.lax.scan(scan_body, h, (gp, cache_slice),
-                              unroll=True if cfg.scan_unroll else 1)
-        for k in cache:
-            new_cache[k].append(kvs[k])
-        off_layers += count
+        h, ncs = _cached_layer_step(cfg, kind, h, lp, attn_fn, ssm_fn)
+        return h, {**cache, **ncs}
 
-    return {k: jnp.concatenate(v, axis=0) for k, v in new_cache.items()}
+    return _scan_layers(cfg, params, h, dict(cache), body)[1]

@@ -1,5 +1,6 @@
 """Deeper attention-variant coverage: MLA absorbed-decode equivalence,
-blocked-vs-naive flash equivalence, MoE capacity behaviour, write_cache."""
+blocked-vs-naive flash equivalence, MoE capacity behaviour, the slot
+cache's row writes."""
 import dataclasses
 
 import jax
@@ -12,7 +13,7 @@ from hypothesis import given, settings, strategies as st
 from repro.configs import get_config, smoke_config
 from repro.models import moe as moe_mod
 from repro.models.attention import (
-    blocked_causal_attention, _naive_causal_attention, write_cache,
+    blocked_causal_attention, _naive_causal_attention, write_cache_rows,
 )
 
 KEY = jax.random.PRNGKey(11)
@@ -56,10 +57,10 @@ def test_blocked_segment_ids():
 # ---------------------------------------------------------------------------
 
 def test_write_cache_scalar_wraps():
-    cache = jnp.zeros((2, 4, 3))
+    cache = jnp.zeros((2, 2, 4, 3))       # (L, B, CL, ...), ring on axis 2
     new = jnp.ones((2, 1, 3))
-    out = write_cache(cache, new, jnp.int32(5))  # 5 % 4 == 1
-    assert float(out[:, 1].sum()) == 6.0
+    out = write_cache_rows(cache, new, 1, jnp.int32(5), 2)  # 5 % 4 == 1
+    assert float(out[1, :, 1].sum()) == 6.0
     assert float(out.sum()) == 6.0
 
 
@@ -67,11 +68,11 @@ def test_write_cache_scalar_wraps():
 @settings(max_examples=20, deadline=None)
 def test_write_cache_per_slot(idx):
     CL = 8
-    cache = jnp.zeros((2, CL, 3))
+    cache = jnp.zeros((2, 2, CL, 3))      # (L, B, CL, ...), ring on axis 2
     new = jnp.ones((2, 1, 3))
-    out = write_cache(cache, new, jnp.asarray(idx))
+    out = write_cache_rows(cache, new, 1, jnp.asarray(idx), 2)
     for b in range(2):
-        assert float(out[b, idx[b] % CL].sum()) == 3.0
+        assert float(out[1, b, idx[b] % CL].sum()) == 3.0
     assert float(out.sum()) == 6.0
 
 

@@ -7,7 +7,7 @@ from repro.configs import (
     ARCH_IDS, SHAPES, all_configs, for_shape, get_config, input_specs,
     smoke_config,
 )
-from repro.configs.base import input_logical, kv_cache_specs
+from repro.configs.base import cache_seq_axis, input_logical, kv_cache_specs
 
 EXPECTED_PARAMS_B = {
     "qwen3-32b": (30, 35),
@@ -69,13 +69,15 @@ def test_long_context_uses_ring_buffer():
     cfg = for_shape(get_config("llama3-8b"), SHAPES["long_500k"])
     assert cfg.attention_variant == "sliding_window"
     cache = kv_cache_specs(cfg, 1, SHAPES["long_500k"].seq_len)
-    assert cache["k"].shape[2] == cfg.sliding_window  # ring buffer, not 524288
+    # ring buffer, not 524288
+    assert cache["k"].shape[cache_seq_axis("k")] == cfg.sliding_window
 
 
 def test_mla_keeps_full_compressed_cache():
     cfg = for_shape(get_config("deepseek-v3-671b"), SHAPES["long_500k"])
     cache = kv_cache_specs(cfg, 1, SHAPES["long_500k"].seq_len)
-    assert cache["c_kv"].shape[2] == SHAPES["long_500k"].seq_len
+    assert (cache["c_kv"].shape[cache_seq_axis("c_kv")]
+            == SHAPES["long_500k"].seq_len)
 
 
 def test_ssm_cache_is_constant_size():

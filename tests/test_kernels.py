@@ -42,10 +42,11 @@ def test_flash_attention_sweep(B, H, KV, S, D, dtype):
 def test_flash_decode_sweep(B, H, KV, CL, D, block, dtype):
     ks = jax.random.split(KEY, 3)
     q = jax.random.normal(ks[0], (B, H, D), dtype)
-    kc = jax.random.normal(ks[1], (B, CL, KV, D), dtype)
-    vc = jax.random.normal(ks[2], (B, CL, KV, D), dtype)
+    kc = jax.random.normal(ks[1], (B, KV, CL, D), dtype)
+    vc = jax.random.normal(ks[2], (B, KV, CL, D), dtype)
     lengths = jnp.arange(1, B + 1) * (CL // (B + 1)) + 1
-    out = ops.flash_decode(q, kc, vc, lengths, scale=D ** -0.5, block_k=block)
+    out = ops.flash_decode(q, kc[None], vc[None], lengths, scale=D ** -0.5,
+                           block_k=block)
     expected = ref.flash_decode_ref(q, kc, vc, lengths, scale=D ** -0.5)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(expected, np.float32), **_tol(dtype))
@@ -56,10 +57,10 @@ def test_flash_decode_full_ring():
     B, H, KV, CL, D = 1, 4, 2, 64, 32
     ks = jax.random.split(KEY, 3)
     q = jax.random.normal(ks[0], (B, H, D))
-    kc = jax.random.normal(ks[1], (B, CL, KV, D))
-    vc = jax.random.normal(ks[2], (B, CL, KV, D))
-    out = ops.flash_decode(q, kc, vc, jnp.full((B,), CL), scale=D ** -0.5,
-                           block_k=32)
+    kc = jax.random.normal(ks[1], (B, KV, CL, D))
+    vc = jax.random.normal(ks[2], (B, KV, CL, D))
+    out = ops.flash_decode(q, kc[None], vc[None], jnp.full((B,), CL),
+                           scale=D ** -0.5, block_k=32)
     expected = ref.flash_decode_ref(q, kc, vc, jnp.full((B,), CL),
                                     scale=D ** -0.5)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expected),
@@ -72,8 +73,8 @@ def test_flash_decode_max_len_hint():
     B, H, KV, CL, D, block = 2, 4, 2, 256, 32, 32
     ks = jax.random.split(KEY, 3)
     q = jax.random.normal(ks[0], (B, H, D))
-    kc = jax.random.normal(ks[1], (B, CL, KV, D))
-    vc = jax.random.normal(ks[2], (B, CL, KV, D))
+    kc = jax.random.normal(ks[1], (1, B, KV, CL, D))
+    vc = jax.random.normal(ks[2], (1, B, KV, CL, D))
     lengths = jnp.asarray([37, 70])
     full = ops.flash_decode(q, kc, vc, lengths, scale=D ** -0.5, block_k=block)
     for hint in (70, 96, 255):   # any hint >= max(lengths) is exact
@@ -96,8 +97,8 @@ def test_prefill_attention_sweep(B, H, KV, C, CL, D, off, block, dtype):
     q = jax.random.normal(ks[0], (B, C, H, D), dtype)
     kh = jax.random.normal(ks[1], (B, C, KV, D), dtype)
     vh = jax.random.normal(ks[2], (B, C, KV, D), dtype)
-    kc = jax.random.normal(ks[3], (B, CL, KV, D), dtype)
-    vc = jax.random.normal(ks[4], (B, CL, KV, D), dtype)
+    kc = jax.random.normal(ks[3], (B, KV, CL, D), dtype)
+    vc = jax.random.normal(ks[4], (B, KV, CL, D), dtype)
     out = ops.prefill_attention(q, kh, vh, kc, vc, jnp.int32(off),
                                 scale=D ** -0.5, block_k=block)
     expected = ref.prefill_attention_ref(q, kh, vh, kc, vc, off,
@@ -119,11 +120,11 @@ def test_prefill_attention_matches_sequential_window():
         kfull = jax.random.normal(ks[0], (B, S, KV, D))
         vfull = jax.random.normal(ks[1], (B, S, KV, D))
         q = jax.random.normal(ks[2], (B, C, H, D))
-        kc = jnp.zeros((B, CL, KV, D))
-        vc = jnp.zeros((B, CL, KV, D))
+        kc = jnp.zeros((B, KV, CL, D))
+        vc = jnp.zeros((B, KV, CL, D))
         for p in range(off):            # the sequential decode loop's writes
-            kc = kc.at[:, p % CL].set(kfull[:, p])
-            vc = vc.at[:, p % CL].set(vfull[:, p])
+            kc = kc.at[:, :, p % CL].set(kfull[:, p])
+            vc = vc.at[:, :, p % CL].set(vfull[:, p])
         out = ops.prefill_attention(q, kfull[:, off:], vfull[:, off:],
                                     kc, vc, jnp.int32(off), scale=D ** -0.5,
                                     block_k=CL)
@@ -152,8 +153,8 @@ def test_prefill_attention_offset_hint():
     q = jax.random.normal(ks[0], (B, C, H, D))
     kh = jax.random.normal(ks[1], (B, C, KV, D))
     vh = jax.random.normal(ks[2], (B, C, KV, D))
-    kc = jax.random.normal(ks[3], (B, CL, KV, D))
-    vc = jax.random.normal(ks[4], (B, CL, KV, D))
+    kc = jax.random.normal(ks[3], (B, KV, CL, D))
+    vc = jax.random.normal(ks[4], (B, KV, CL, D))
     for off in (0, 40, 96, 300):    # 300 > CL: wrapped ring, all slots live
         full = ops.prefill_attention(q, kh, vh, kc, vc, jnp.int32(off),
                                      scale=D ** -0.5, block_k=block)

@@ -90,8 +90,8 @@ def test_decode_matches_forward(arch):
     cache = pre["cache"]
 
     def pad(k, v):  # headroom so decode can write at index S
-        if k in ("k", "v"):
-            return jnp.pad(v, ((0, 0), (0, 0), (0, 4), (0, 0), (0, 0)))
+        if k in ("k", "v"):   # head-major (L,B,KV,CL,D)
+            return jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, 4), (0, 0)))
         if k in ("c_kv", "k_rope"):
             return jnp.pad(v, ((0, 0), (0, 0), (0, 4), (0, 0)))
         return v

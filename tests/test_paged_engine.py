@@ -259,8 +259,8 @@ def test_paged_kernel_bitwise_when_page_equals_block():
     bt = np.arange(1, n_pages).reshape(B, NBe).astype(np.int32)
     lengths = np.array([CL, 5, 9], np.int32)
     q = rng.standard_normal((B, H, D)).astype(np.float32)
-    kg = gather_pages(pool_k, bt)
-    vg = gather_pages(pool_v, bt)
+    kg = np.swapaxes(gather_pages(pool_k, bt), 1, 2)[None]  # (1,B,KV,CL,D)
+    vg = np.swapaxes(gather_pages(pool_v, bt), 1, 2)[None]
     ref = kops.flash_decode(q, kg, vg, lengths, scale=0.5, block_k=blk)
     out = kops.flash_decode_paged(q, pool_k, pool_v, bt, lengths, scale=0.5)
     np.testing.assert_array_equal(np.asarray(ref), np.asarray(out))

@@ -42,7 +42,7 @@ def test_decode_parity(arch):
 
     def pad(k, v):  # pad cache to 64 so the decode kernel engages
         if k in ("k", "v"):
-            return jnp.pad(v, ((0, 0), (0, 0), (0, 64 - S), (0, 0), (0, 0)))
+            return jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, 64 - S), (0, 0)))
         return v
 
     cache = {k: pad(k, v) for k, v in pre["cache"].items()}

@@ -14,6 +14,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config, smoke_config
+from repro.configs.base import cache_seq_axis
 from repro.configs.tiny import config as tiny_config
 from repro.core.rollout import EngineConfig, GenerationEngine
 from repro.data.math_task import MathTask, Problem
@@ -187,7 +188,8 @@ def test_prefill_does_not_disturb_inflight_slots():
     eng.refill()          # admits slot 1, chunked prefill runs
     assert eng.n_active == 2
     k_after = np.asarray(eng.state["cache"]["k"])[:, 0]
-    np.testing.assert_array_equal(k_before[:, :n0], k_after[:, :n0])
+    # head-major (L,KV,CL,D) per slot: positions on axis 2
+    np.testing.assert_array_equal(k_before[:, :, :n0], k_after[:, :, :n0])
 
 
 def _ring_cfg(arch, window=8):
@@ -222,10 +224,11 @@ def test_ring_prefill_matches_sequential(arch):
     eB = GenerationEngine(cfg, params, ecB, _list_source(probs), seed=11)
     if arch in ("gqa", "hybrid"):
         key = "k"
-        assert eA.state["cache"][key].shape[2] == 8   # a real ring
+        assert eA.state["cache"][key].shape[cache_seq_axis(key)] == 8
     elif arch == "mla":
         key = "c_kv"
-        assert eA.state["cache"][key].shape[2] == 24  # MLA stays full-length
+        # MLA stays full-length
+        assert eA.state["cache"][key].shape[cache_seq_axis(key)] == 24
     # ring caches no longer force the legacy loop
     assert eA.prefill_chunk_size == 4
     assert eA.refill() == 4 and eB.refill() == 4
@@ -238,6 +241,9 @@ def test_ring_prefill_matches_sequential(arch):
         if k in ("conv", "ssd"):
             np.testing.assert_allclose(a, b, atol=1e-5, err_msg=k)
         else:
+            # ring positions on axis 2, whatever the leaf's layout
+            a = np.moveaxis(a, cache_seq_axis(k), 2)
+            b = np.moveaxis(b, cache_seq_axis(k), 2)
             CL = a.shape[2]
             for s in range(4):
                 m = min(int(eA._host_ncached[s]), CL)  # wrapped => all slots
@@ -320,7 +326,8 @@ def test_prefill_kernel_in_engine_matches_jnp(arch, ring):
     kcfg = dataclasses.replace(cfg, use_pallas=True)
     engK = GenerationEngine(kcfg, params, ec, _list_source(probs), seed=4)
     from repro.models.attention import _use_prefill_kernel
-    CL = eng.state["cache"]["k" if arch == "gqa" else "c_kv"].shape[2]
+    key = "k" if arch == "gqa" else "c_kv"
+    CL = eng.state["cache"][key].shape[cache_seq_axis(key)]
     assert _use_prefill_kernel(kcfg, engK.prefill_chunk_size, CL)
     eng.refill(), engK.refill()
     for k in eng.state["cache"]:

@@ -31,7 +31,7 @@ def test_ring_decode_matches_windowed_forward():
     # ring decode: window-sized cache, token by token
     specs = kv_cache_specs(cfg, B, W)
     cache = {k: jnp.zeros(v.shape, v.dtype) for k, v in specs.items()}
-    assert cache["k"].shape[2] == W  # the ring really is window-sized
+    assert cache["k"].shape[3] == W  # the ring really is window-sized
     logits = []
     for t in range(S):
         out = M.decode_step(params, toks[:, t:t + 1], pos[:, t:t + 1],
@@ -71,7 +71,7 @@ def test_ring_recompute_kv_matches_sequential_writes():
         "cache": {k: jax.random.normal(KEY, v.shape).astype(v.dtype)
                   for k, v in specs.items()},   # stale garbage everywhere
     }
-    assert st["cache"]["k"].shape[2] == W
+    assert st["cache"]["k"].shape[3] == W   # head-major (L,H,KV,W,D)
 
     got = GenerationEngine._recompute_impl(new_params, st, cfg=cfg)
 
@@ -84,12 +84,12 @@ def test_ring_recompute_kv_matches_sequential_writes():
         valid = np.zeros((H, W), bool)
         for b, nc in enumerate(np.asarray(n_cached)):
             for p in range(int(nc)):
-                exp[:, b, p % W] = np.asarray(full[key][:, b, p])
+                exp[:, b, :, p % W] = np.asarray(full[key][:, b, :, p])
                 valid[b, p % W] = True
         g = np.asarray(got[key], np.float32)
         for b in range(H):
             np.testing.assert_allclose(
-                g[:, b][:, valid[b]], exp[:, b][:, valid[b]],
+                g[:, b][:, :, valid[b]], exp[:, b][:, :, valid[b]],
                 atol=1e-5, rtol=1e-5, err_msg=f"{key} row {b}")
         # dead slots of empty rows must never be read anyway; nothing to
         # assert there (the gather clamps them to position 0)

@@ -162,6 +162,26 @@ def test_pipeline_run_spans_nest_by_layer(small_run):
     assert parents["engine.install"] == {"actor.tick"}
 
 
+@pytest.mark.parametrize("cache, inplace", [("slots", 1), ("paged", 0)])
+def test_decode_span_counts_kv_inplace(cache, inplace):
+    """`engine.decode` counts `kv_inplace`: 1 where the step writes its K/V
+    rows into the slot cache in place, 0 on the paged pools."""
+    from repro.core.rollout import GenerationEngine
+    task = MathTask(max_operand=5, ops="+")
+    cfg = tiny_config(vocab_size=task.tok.vocab_size, d_model=64, n_layers=1)
+    params = tree_values(M.init_params(cfg, jax.random.PRNGKey(0)))
+    eng = GenerationEngine(cfg, params,
+                           EngineConfig(n_slots=2, max_len=16, cache=cache),
+                           task.sample)
+    eng.refill()
+    t0 = time.perf_counter()
+    for _ in range(3):
+        eng.step(task)
+    decode = [r for r in _since(t0) if r.name == "engine.decode"]
+    assert len(decode) == 3
+    assert [r.counts["kv_inplace"] for r in decode] == [inplace] * 3
+
+
 @pytest.fixture(scope="module")
 def programs(small_run):
     """Each jitted program of the engine and trainer, lowered on the

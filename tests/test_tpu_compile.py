@@ -14,12 +14,15 @@ module is imported.
 """
 import dataclasses
 import functools
+import math
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 
 B, H, KV, D, CL = 32, 32, 8, 64, 1024     # engine slots, heads, cache
+L = 4                                      # engine layers
 N, DM, V = 4096, 2048, 49155               # loss rows, d_model, vocab
 BF = jnp.bfloat16
 
@@ -61,8 +64,8 @@ def _kernel_case(name):
     if name == "flash_decode":
         return (functools.partial(kops.flash_decode, scale=0.125,
                                   interpret=False),
-                _sds((B, H, D)), _sds((B, CL, KV, D)), _sds((B, CL, KV, D)),
-                _sds((B,), i32))
+                _sds((B, H, D)), _sds((L, B, KV, CL, D)),
+                _sds((L, B, KV, CL, D)), _sds((B,), i32), _sds((), i32))
     if name == "flash_decode_paged":
         ps = 16
         np_ = B * CL // ps + 1
@@ -76,7 +79,7 @@ def _kernel_case(name):
         return (functools.partial(kops.prefill_attention, scale=0.125,
                                   interpret=False),
                 _sds((B, c, H, D)), _sds((B, c, KV, D)), _sds((B, c, KV, D)),
-                _sds((B, CL, KV, D)), _sds((B, CL, KV, D)), _sds((), i32))
+                _sds((B, KV, CL, D)), _sds((B, KV, CL, D)), _sds((), i32))
     if name == "flash_attention":
         s = 1024
         return (functools.partial(kops.flash_attention, scale=0.125,
@@ -130,7 +133,7 @@ def test_engine_decode_step_compiles_for_v5e(one_chip):
     from repro.models import model as M
     from repro.sharding import tree_values
 
-    cfg = dataclasses.replace(granite(), n_layers=4, use_pallas=True,
+    cfg = dataclasses.replace(granite(), n_layers=L, use_pallas=True,
                               pallas_interpret=False)
     ec = EngineConfig(n_slots=B, max_len=CL, interpret=False)
     params = jax.eval_shape(
@@ -146,3 +149,64 @@ def test_engine_decode_step_compiles_for_v5e(one_chip):
                              kv_len_hint=CL)
     text = _compile_text(one_chip, step, params, state)
     assert "tpu_custom_call" in text
+
+
+# one layer's K or V in elements, and the whole cache in bytes (bf16)
+LAYER_KV = B * KV * CL * D
+CACHE_BYTES = 2 * L * LAYER_KV * 2
+# what may output a cache-sized array inside the step: the arguments, the
+# layer loop's carry, and the in-place row writes
+IN_PLACE = {"parameter", "get-tuple-element", "tuple", "while", "bitcast",
+            "dynamic-update-slice"}
+
+
+def test_engine_decode_step_updates_cache_in_place_for_v5e(one_chip):
+    """The engine's own jitted decode step, with its state donated as the
+    engine runs it: each layer writes one K and one V row per slot into
+    the cache in place, and no copy, transpose or fusion makes an array
+    the size of a layer's K or V (the kernel reads the cache's own
+    layout). The output cache aliases the donated input, and the
+    temporaries stay under one layer's K+V."""
+    from repro.configs.base import kv_cache_specs
+    from repro.configs.granite_3_2b import config as granite
+    from repro.core.rollout import EngineConfig, decode_program
+    from repro.models import model as M
+    from repro.sharding import tree_values
+
+    cfg = dataclasses.replace(granite(), n_layers=L, use_pallas=True,
+                              pallas_interpret=False)
+    ec = EngineConfig(n_slots=B, max_len=CL, interpret=False)
+
+    def placed(s):
+        return jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+
+    params = jax.tree.map(placed, jax.eval_shape(
+        lambda: tree_values(M.init_params(cfg, jax.random.PRNGKey(0)))))
+    state = {"tokens": _sds((B, CL), jnp.int32),
+             "lp": _sds((B, CL), jnp.float32),
+             "n_cached": _sds((B,), jnp.int32),
+             "prompt_len": _sds((B,), jnp.int32),
+             "active": _sds((B,), bool),
+             "cache": kv_cache_specs(cfg, B, CL),
+             "key": _sds((2,), jnp.uint32)}
+    state = jax.tree.map(placed, state)
+    compiled = decode_program(cfg, ec).lower(
+        params, state, None, kv_len_hint=CL).compile()
+
+    # (opcode, line) of each instruction whose output holds a layer's K or V
+    big = []
+    for line in compiled.as_text().splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = \w+\[([\d,]+)\]\{[^}]*\} ([\w-]+)\(",
+                     line)
+        dims = [int(d) for d in m.group(1).split(",")] if m else []
+        if (math.prod(dims) >= LAYER_KV and CL in dims and KV in dims
+                and D in dims):
+            big.append((m.group(2), line.strip()[:160]))
+    assert big, "no cache-sized array found: the parse is stale"
+    assert not [b for b in big if b[0] not in IN_PLACE], big
+    # one K and one V row per slot, in the body of the layer loop
+    assert sum(op == "dynamic-update-slice" for op, _ in big) == 2 * B
+
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= CACHE_BYTES
+    assert mem.temp_size_in_bytes < 2 * LAYER_KV * 2
