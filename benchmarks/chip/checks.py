@@ -27,15 +27,17 @@ from typing import Dict, List, Optional
 import numpy as np
 
 import reference as R
+import weights as W
 
 UPDATE_LEAF_FLOOR = 1e-3
 
 
 def install_mismatch(pipe, max_events: int = 200_000) -> int:
     """Stop the trainer, let the published weights land, and count the
-    versions and leaves by which each engine differs from the trainer."""
+    versions and leaves by which each engine differs from the trainer.
+    Leaves are compared on the host, one at a time: on a mesh the engines
+    and the trainer hold them on different devices."""
     import jax
-    import jax.numpy as jnp
     pipe.trainer_stage.failed = True     # no further optimizer steps
     tv = pipe.trainer.version
     try:
@@ -44,12 +46,13 @@ def install_mismatch(pipe, max_events: int = 200_000) -> int:
                       max_events=max_events)
     except RuntimeError:
         pass
-    bad = 0
+    bad = sum(abs(tv - int(e.version)) for e in pipe.engines)
     tp = jax.tree_util.tree_leaves(pipe.trainer.params)
-    for e in pipe.engines:
-        bad += abs(tv - int(e.version))
-        for a, b in zip(jax.tree_util.tree_leaves(e.params), tp):
-            bad += int(not bool(jnp.array_equal(a, b)))
+    eps = [jax.tree_util.tree_leaves(e.params) for e in pipe.engines]
+    for i, b in enumerate(tp):
+        b = np.asarray(b)
+        for leaves in eps:
+            bad += int(not np.array_equal(np.asarray(leaves[i]), b))
     return bad
 
 
@@ -99,9 +102,11 @@ def program_readings(pipe, rec, cell, seed: int) -> dict:
 def reference_readings(cell, seed: int, batches, rollouts, prec: str = "f32",
                        keep_rows=None) -> dict:
     wl, c = cell.workload, cell.config
+    mesh = W.cell_mesh(wl, cell.chips)
     tr = R.train_steps(c, seed, batches, wl["optimizer"], wl["rl"], prec,
-                       keep_rows=keep_rows)
-    lps = R.rollout_logprobs(c, seed, rollouts, cell.mix["max_len"], prec)
+                       keep_rows=keep_rows, mesh=mesh)
+    lps = R.rollout_logprobs(c, seed, rollouts, cell.mix["max_len"], prec,
+                             mesh=mesh)
     return {"losses": tr["losses"], "grad_norms": tr["grad_norms"],
             "raw_grad_norms": tr["raw_grad_norms"],
             "change_norms": tr["change_norms"], "rollout_lps": lps}

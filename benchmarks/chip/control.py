@@ -15,7 +15,10 @@ against the same float32 reference,
 - the altered-token fault: one sampled token of every compared rollout
   replaced by another, its carried logprob kept.
 (A step that returns its state unchanged reads 1 on update_gap and
-grad_gap by construction and needs no run.)
+grad_gap by construction and needs no run; a publication that leaves
+out the exchange between chips is `install_mismatch`'s, shown by a CPU
+test.) On a cell with a mesh the program and every reference run on the
+cell's chips.
 """
 from __future__ import annotations
 
@@ -53,6 +56,7 @@ def readings_for_seed(cell, seed: int, log) -> dict:
     import checks
     import harness
     import reference
+    import weights
     t0 = time.perf_counter()
     pipe, rec, _ = harness.build(cell, seed, interpret=False)
     harness.warm_up(pipe, cell)
@@ -74,8 +78,9 @@ def readings_for_seed(cell, seed: int, log) -> dict:
                                      keep_rows=list(range(rows // 2)))
     row["half_batch"] = checks.numbers(half, ref, ro)
     alt = altered(ro, cell.config["vocab_size"])
-    lps = reference.rollout_logprobs(cell.config, seed, alt,
-                                     cell.mix["max_len"])
+    lps = reference.rollout_logprobs(
+        cell.config, seed, alt, cell.mix["max_len"],
+        mesh=weights.cell_mesh(cell.workload, cell.chips))
     row["altered_token"] = {"engine_lp_gap": checks.numbers(
         prog, dict(ref, rollout_lps=lps), ro)["engine_lp_gap"]}
     # the three steps' losses are read but not compared: the float8

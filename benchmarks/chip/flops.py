@@ -7,23 +7,19 @@ from __future__ import annotations
 
 from typing import Iterable
 
+import weights as W
+
 
 def matmul_params(c: dict) -> int:
-    """Weights that take part in a matrix product per token (the input
-    embedding is a lookup and is left out; the value head is one column)."""
-    d, F, V = c["hidden_size"], c["intermediate_size"], c["vocab_size"]
-    H, KV, Dh = (c["num_attention_heads"], c["num_key_value_heads"],
-                 c["head_dim"])
-    per_layer = d * H * Dh * 2 + d * KV * Dh * 2 + 3 * d * F
-    head = d * V + (d if c.get("value_head") else 0)
-    return c["num_hidden_layers"] * per_layer + head
+    """Weights that take part in a matrix product per token, as the
+    configuration's architecture module (`arch/<arch>.py`) counts them."""
+    return W.arch(c).matmul_params(c)
 
 
 def attn_flops_per_token(c: dict, context: int) -> int:
-    """Scores and weighted values of one query against `context` keys,
-    over all layers (forward)."""
-    return (4 * c["num_hidden_layers"] * c["num_attention_heads"]
-            * c["head_dim"] * context)
+    """Attention FLOPs of one query against `context` keys, over all
+    layers (forward), as the architecture module counts them."""
+    return W.arch(c).attn_flops_per_token(c, context)
 
 
 def forward_flops(c: dict, tokens: int, context_sum: int) -> int:

@@ -2,8 +2,9 @@
 
 Everything a cell needs is found by name from files: its entry in
 `BENCHMARK.json`, `workloads/<cell>.json` (engine, pipeline, optimizer
-settings and the limits of the correctness check), the configuration
-file the entry names, `traffic/<mix>.json`, and one reader per per-layer
+settings, an optional mesh, and the limits of the correctness check),
+the configuration file the entry names and its architecture module
+`arch/<arch>.py`, `traffic/<mix>.json`, and one reader per per-layer
 metric in `metrics/<metric>.py`.
 """
 from __future__ import annotations
@@ -12,7 +13,6 @@ import contextlib
 import dataclasses
 import functools
 import gc
-import importlib.util
 import json
 import os
 import shutil
@@ -73,44 +73,13 @@ def load_cell(name: str, bench_path: Optional[str] = None) -> Cell:
 
 
 def load_reader(metric: str):
-    path = os.path.join(HERE, "metrics", metric + ".py")
-    spec = importlib.util.spec_from_file_location("metric_" + metric.replace(
-        ".", "_").replace("-", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return W.load_file(os.path.join(HERE, "metrics", metric + ".py"),
+                       "metric", metric).read
 
 
 # ---------------------------------------------------------------------------
 # the system under test
 # ---------------------------------------------------------------------------
-
-def program_config(c: dict, interpret: Optional[bool]):
-    """The program's ModelConfig for a configuration file. Refuses a file
-    the program cannot run as stated."""
-    import jax.numpy as jnp
-    from repro.configs.base import ModelConfig
-    plain = (c["embedding_multiplier"] == 1.0 and c["residual_multiplier"]
-             == 1.0 and c["logits_scaling"] == 1.0
-             and abs(c["attention_multiplier"] * c["head_dim"] ** 0.5 - 1)
-             < 1e-9 and c["hidden_act"] == "silu" and c["arch"] == "dense")
-    if not plain:
-        raise ValueError(f"{c['name']}: the program runs only the plain "
-                         "dense block (no Granite multipliers)")
-    prog = c["program"]
-    return ModelConfig(
-        name=c["name"], arch_type="dense", n_layers=c["num_hidden_layers"],
-        d_model=c["hidden_size"], n_heads=c["num_attention_heads"],
-        n_kv_heads=c["num_key_value_heads"], d_head=c["head_dim"],
-        d_ff=c["intermediate_size"], vocab_size=c["vocab_size"],
-        rope_theta=c["rope_theta"], norm_eps=c["rms_norm_eps"],
-        tie_embeddings=c["tie_word_embeddings"],
-        use_value_head=bool(c.get("value_head")),
-        dtype=W.DTYPES[c["dtype"]], use_pallas=prog["use_pallas"],
-        pallas_interpret=interpret if prog["use_pallas"] else None,
-        fused_loss=prog["fused_loss"], remat=prog["remat"],
-        source=c["source"])
-
 
 def annotate(name: str):
     import jax
@@ -122,8 +91,10 @@ class Recorder:
     the first steps (their batches, the first gradient from Adam's state,
     the parameters' change after three steps) and counts trained work."""
 
-    def __init__(self, c: dict, seed: int, n_keep: int, b1: float):
+    def __init__(self, c: dict, seed: int, n_keep: int, b1: float,
+                 mesh=None):
         self.c, self.seed, self.n_keep, self.b1 = c, seed, n_keep, b1
+        self.mesh = mesh
         self.steps = 0
         self.batches: List[Dict[str, np.ndarray]] = []
         self.metrics: List[Any] = []
@@ -137,12 +108,12 @@ class Recorder:
 
     def before(self, trainer, batch) -> None:
         if self.steps == 1:
-            m = W.from_program_tree(trainer.state.opt.m)
+            m = W.arch(self.c).from_program_tree(trainer.state.opt.m)
             self.grad_norms = {k: v / (1.0 - self.b1)
                                for k, v in _host(_norm_fns()[0](m)).items()}
         elif self.steps == 3:
-            p0 = W.make_weights(self.c, self.seed)
-            p3 = W.from_program_tree(trainer.state.params)
+            p0 = W.make_weights(self.c, self.seed, self.mesh)
+            p3 = W.arch(self.c).from_program_tree(trainer.state.params)
             self.change_norms = _host(_norm_fns()[1](p3, p0))
             del p0
         if len(self.batches) < self.n_keep:
@@ -239,6 +210,11 @@ def instrument(pipe, counters: Counters) -> None:
 
 
 def build(cell: Cell, seed: int, interpret: Optional[bool]):
+    """The public `PipelineRL` for a cell, with the benchmark's seeded
+    weights, prompt source and recording trainer. A workload with a
+    `mesh` gets that mesh over its chips: the trainer's state is split
+    over it, the engines take disjoint submeshes of it, and publications
+    run through the program's `MeshBroadcastExecutor`."""
     import jax
     from repro.core.pipeline import PipelineConfig, PipelineRL
     from repro.core.algo import RLConfig
@@ -248,8 +224,13 @@ def build(cell: Cell, seed: int, interpret: Optional[bool]):
     from repro.sharding import tree_values
 
     c, wl = cell.config, cell.workload
-    cfg = program_config(c, interpret)
-    params = W.to_program_tree(W.make_weights(c, seed), c)
+    arch = W.arch(c)
+    cfg = arch.program_config(c, interpret)
+    mesh = W.cell_mesh(wl, cell.chips)
+    if mesh is not None and cfg.use_pallas:
+        raise ValueError(f"{c['name']}: a cell on a mesh needs use_pallas "
+                         "false (GSPMD cannot partition a Mosaic kernel)")
+    params = arch.to_program_tree(W.make_weights(c, seed, mesh), c)
     want = jax.tree.map(lambda a: (a.shape, a.dtype),
                         tree_values(M.init_params(cfg, abstract=True)))
     got = jax.tree.map(lambda a: (a.shape, a.dtype), params)
@@ -257,16 +238,19 @@ def build(cell: Cell, seed: int, interpret: Optional[bool]):
         raise ValueError("benchmark weights do not match the program's "
                          "parameter tree")
     traffic = traffic_gen.Traffic(cell.mix, seed)
-    rec = Recorder(c, seed, max(wl["warmup_steps"], 3), wl["optimizer"]["b1"])
+    rec = Recorder(c, seed, max(wl["warmup_steps"], 3), wl["optimizer"]["b1"],
+                   mesh)
     trainer = make_trainer_class(rec)(
-        cfg, params, rl=RLConfig(**wl["rl"]), adam=AdamConfig(**wl["optimizer"]))
+        cfg, params, rl=RLConfig(**wl["rl"]), adam=AdamConfig(**wl["optimizer"]),
+        mesh=mesh)
     ec = EngineConfig(max_len=cell.mix["max_len"],
                       temperature=cell.mix["temperature"],
                       eos_id=traffic_gen.eos_id(cell.mix), interpret=interpret,
                       **wl["engine"])
     pc = PipelineConfig(n_opt_steps=wl["warmup_steps"], **wl["pipeline"])
     pipe = PipelineRL(cfg, params, traffic, ec, pc, trainer=trainer,
-                      seed=W.seed31(seed, 2), prompt_source=traffic.source)
+                      seed=W.seed31(seed, 2), prompt_source=traffic.source,
+                      mesh=mesh)
     return pipe, rec, traffic
 
 
@@ -275,6 +259,7 @@ def build(cell: Cell, seed: int, interpret: Optional[bool]):
 # ---------------------------------------------------------------------------
 
 def _snapshot(pipe, rec: Recorder, traffic, counters: Counters) -> dict:
+    bs = pipe.broadcast_stats()
     return {
         "loss_tokens": rec.loss_tokens, "steps": rec.steps,
         "seq_tokens": rec.seq_tokens, "ctx_sum": rec.ctx_sum,
@@ -283,6 +268,7 @@ def _snapshot(pipe, rec: Recorder, traffic, counters: Counters) -> dict:
         "sampled": sum(e.tokens_generated for e in pipe.engines),
         "prefill_tokens": sum(e.prefill_tokens for e in pipe.engines),
         "done": len(traffic.done),
+        "executed": bs["executed"], "exec_seconds": bs["exec_seconds"],
         "lost": sum(a.rollouts_lost for a in pipe.actors)
                 + sum(e.prompts_rejected for e in pipe.engines),
         **dataclasses.asdict(counters),
@@ -415,10 +401,15 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
         used = [k for k in tr.devices if tr.ops[k]]
         busy = [PR.busy_seconds(tr.ops[k], tr.span) for k in used]
         allops = [o for k in used for o in tr.ops[k]]
+        gaps = [g for k in used
+                for g in PR.top_gaps(tr.ops[k], tr.spans, tr.span)]
         result["breakdown"] = {
             "device_ops": PR.top_ops(allops),
-            "idle_gaps": PR.top_gaps(tr.ops[used[0]], tr.spans, tr.span)
-            if used else []}
+            "idle_gaps": sorted(gaps, key=lambda g: -g[1])[:10]}
+        roles = PR.module_roles(tr)
+        for role in PR.ROLES:
+            log(f"programs in {role}: "
+                f"{sorted({m.name for m in roles[role]})}")
         for role in ("decode", "train_step"):
             names = sorted({PR.op_label(o.name) for o in PR.kernels(tr, role)})
             log(f"kernels in {role} programs: {names}")

@@ -203,6 +203,18 @@ def module_roles(tr: "Trace") -> Dict[str, List[Op]]:
     return out
 
 
+def role_seconds_by_plane(tr: "Trace", role: str) -> List[float]:
+    """Device seconds of a role's program executions in the window, on
+    each device plane that ran one."""
+    mine = {id(m) for m in module_roles(tr)[role]}
+    out = []
+    for mods in tr.modules.values():
+        t = summed([m for m in mods if id(m) in mine], tr.span)
+        if t > 0:
+            out.append(t)
+    return out
+
+
 def ops_within(ops: Sequence[Op], within: Sequence[Op]) -> List[Op]:
     """Operations that start inside one of the `within` intervals."""
     iv = sorted((w.start, w.end) for w in within)

@@ -1,4 +1,5 @@
-"""Plain reference of a dense decoder and of one PipelineRL optimizer step.
+"""Plain reference of the configuration's decoder and of one PipelineRL
+optimizer step.
 
 Straightforward `jax.numpy` in float32 under `default_matmul_precision
 ("highest")`: no kernels, no cache, no packing tricks, one packed row or
@@ -7,22 +8,26 @@ It imports nothing of the program and takes no array the program made:
 its weights come from `weights.make_weights` with the run's seed, its
 inputs are the prompts and sampled tokens (and the behaviour logprobs the
 RL loss is defined on), and its hyperparameters come from the cell's
-files.
+files. On a cell with a mesh the same code runs with its arrays split
+over the mesh (`weights.leaf_sharding`): the float32 weights, gradients
+and Adam moments of a deeper model do not fit one chip.
 
 `prec="fp8"` is the control: every matrix product takes its operands
 through float8 e4m3 with one scale per tensor, the step below the bf16
 the configuration states. A sound comparison has to fail it.
 
-Follows the configuration file: RMSNorm, rotary embeddings on split
-halves, grouped-query causal attention inside each packed segment, SwiGLU,
-untied or tied head, optional value head; the multipliers in the file
-(embedding, residual, attention, logits) are applied as stated.
-Parameters are stored in the configuration's dtype after each update, as
-the configuration states; the arithmetic is float32.
+The block itself is the architecture module's `forward` (for "dense":
+RMSNorm, rotary embeddings on split halves, grouped-query causal
+attention inside each packed segment, SwiGLU, untied or tied head,
+optional value head; the multipliers in the file are applied as stated),
+built from `mm`, `rms_norm` and `rope` here. Parameters are stored in
+the configuration's dtype after each update, as the configuration
+states; the arithmetic is float32.
 """
 from __future__ import annotations
 
 import functools
+import json
 from typing import Dict, List, Optional, Sequence
 
 import jax
@@ -67,47 +72,9 @@ def rope(x, positions, theta):
 
 
 def forward(w, tokens, positions, segment_ids, c: dict, prec: str):
-    """One row. tokens/positions/segment_ids: (S,). Returns logits (S, V)
-    (row t scores token t+1) and values (S,) or None."""
-    f32 = lambda a: a.astype(jnp.float32)
-    eps = c["rms_norm_eps"]
-    H, KV, Dh = (c["num_attention_heads"], c["num_key_value_heads"],
-                 c["head_dim"])
-    S = tokens.shape[0]
-    h = f32(w["embed"])[tokens] * c["embedding_multiplier"]
-    i, j = jnp.arange(S)[:, None], jnp.arange(S)[None, :]
-    mask = (i >= j) & (segment_ids[:, None] == segment_ids[None, :])
-    for layer in range(c["num_hidden_layers"]):
-        lw = lambda k: f32(w[k][layer])
-        x = rms_norm(h, lw("norm1"), eps)
-        q = rope(mm("sd,dhk->shk", x, lw("wq"), prec), positions,
-                 c["rope_theta"])
-        k = rope(mm("sd,dhk->shk", x, lw("wk"), prec), positions,
-                 c["rope_theta"])
-        v = mm("sd,dhk->shk", x, lw("wv"), prec)
-        qg = q.reshape(S, KV, H // KV, Dh)
-        s = mm("qgrd,kgd->grqk", qg, k, prec) * c["attention_multiplier"]
-        s = jnp.where(mask[None, None], s, -1e30)
-        p = jax.nn.softmax(s, axis=-1)
-        o = mm("grqk,kgd->qgrd", p, v, prec).reshape(S, H, Dh)
-        h = h + c["residual_multiplier"] * mm("shk,hkd->sd", o, lw("wo"),
-                                              prec)
-        x = rms_norm(h, lw("norm2"), eps)
-        g = mm("sd,df->sf", x, lw("w_gate"), prec)
-        u = mm("sd,df->sf", x, lw("w_up"), prec)
-        h = h + c["residual_multiplier"] * mm(
-            "sf,fd->sd", jax.nn.silu(g) * u, lw("w_down"), prec)
-    hn = rms_norm(h, f32(w["final_norm"]), eps)
-    if c["tie_word_embeddings"]:
-        logits = mm("sd,vd->sv", hn, w["embed"], prec)
-    else:
-        logits = mm("sd,dv->sv", hn, w["lm_head"], prec)
-    logits = logits / c["logits_scaling"]
-    values = None
-    if "value_head" in w:
-        values = jnp.einsum("sd,dk->sk", hn, f32(w["value_head"]),
-                            precision=HIGHEST)[:, 0]
-    return logits, values
+    """One row through the configuration's architecture (`arch/<arch>.py`):
+    logits (S, V) (row t scores token t+1) and values (S,) or None."""
+    return W.arch(c).forward(w, tokens, positions, segment_ids, c, prec)
 
 
 def token_logprobs(logits, tokens):
@@ -140,15 +107,15 @@ def row_loss(w, row: Dict[str, jax.Array], n_total, c: dict, rl: dict,
 
 
 @functools.lru_cache(maxsize=None)
-def _row_grad(spec: tuple, rl_spec: tuple, prec: str):
-    c, rl = dict(spec), dict(rl_spec)
+def _row_grad(spec: str, rl_spec: tuple, prec: str):
+    c, rl = json.loads(spec), dict(rl_spec)
     return jax.jit(jax.value_and_grad(
         lambda w, row, n: row_loss(w, row, n, c, rl, prec)))
 
 
 @functools.lru_cache(maxsize=None)
-def _logprob_fn(spec: tuple, prec: str):
-    c = dict(spec)
+def _logprob_fn(spec: str, prec: str):
+    c = json.loads(spec)
 
     @jax.jit
     def f(w, tokens, positions, segment_ids):
@@ -174,20 +141,21 @@ def leaf_norms(tree) -> Dict[str, float]:
 
 def train_steps(c: dict, seed: int, batches: Sequence[Dict[str, np.ndarray]],
                 opt: dict, rl: dict, prec: str = "f32",
-                keep_rows: Optional[Sequence[int]] = None) -> dict:
+                keep_rows: Optional[Sequence[int]] = None,
+                mesh=None) -> dict:
     """Follow the program's first len(batches) optimizer steps from the
     seed's weights. Returns each step's loss, the per-leaf norms of the
     first step's gradient as Adam receives it (after clipping), the
     per-leaf norms of the unclipped first gradient, and the per-leaf norms
     of the parameters' change after the last step. `keep_rows` plants the
     half-batch fault: the other rows are left out and the mean is taken
-    over the rest."""
-    w0 = W.make_weights(c, seed)
+    over the rest. With a mesh every array is split over it."""
+    w0 = W.make_weights(c, seed, mesh)
     dtypes = {k: v.dtype for k, v in w0.items()}
     p = {k: v.astype(jnp.float32) for k, v in w0.items()}
     m = {k: jnp.zeros_like(v) for k, v in p.items()}
     v2 = {k: jnp.zeros_like(v) for k, v in p.items()}
-    grad_fn = _row_grad(_frozen(c), _frozen(rl), prec)
+    grad_fn = _row_grad(W.frozen(c), _frozen(rl), prec)
     out = {"losses": [], "grad_norms": None, "raw_grad_norms": None}
     with jax.default_matmul_precision("highest"):
         for step, batch in enumerate(batches, start=1):
@@ -226,12 +194,13 @@ def train_steps(c: dict, seed: int, batches: Sequence[Dict[str, np.ndarray]],
 
 
 def rollout_logprobs(c: dict, seed: int, rollouts: List[Dict[str, np.ndarray]],
-                     pad_to: int, prec: str = "f32") -> List[np.ndarray]:
+                     pad_to: int, prec: str = "f32",
+                     mesh=None) -> List[np.ndarray]:
     """Reference logprob of every token of each rollout (prompt + sampled
     tokens), under the seed's weights. Rollouts are padded to `pad_to`
     (padding sits after the rollout, so causal attention never sees it)."""
-    w = W.make_weights(c, seed)
-    f = _logprob_fn(_frozen(c), prec)
+    w = W.make_weights(c, seed, mesh)
+    f = _logprob_fn(W.frozen(c), prec)
     out = []
     with jax.default_matmul_precision("highest"):
         for r in rollouts:

@@ -24,15 +24,24 @@ window with the JAX profiler and reports the per-layer metrics instead.
 Layout (a later PR adds files and `BENCHMARK.json` entries; it edits no
 file that is here):
 
-    configs/<config>.json    sizes as run, source, cuts (`reduced`), `assumed`
+    configs/<config>.json    sizes as run, source, cuts (`reduced`), `assumed`,
+                             `arch` (which module below runs it)
+    arch/<arch>.py           one module per architecture: the program's
+                             config, the weights' leaves and their mapping
+                             to the program's tree, the reference block,
+                             its matmul and attention counts
     traffic/<mix>.json       parameters of one traffic mix (traffic_gen.py)
-    workloads/<cell>.json    engine, pipeline, optimizer settings and the
+    workloads/<cell>.json    engine, pipeline, optimizer settings, an
+                             optional `mesh` over the cell's chips, and the
                              limits of the correctness check
     metrics/<metric>.py      one reader per per-layer metric: read(ctx)
                              returns a number, or None when it finds nothing
     peaks.py                 published peaks by device_kind
     flops.py                 operations and bytes from the shapes
     profile_reduce.py        trace -> busy time, kernel time, idle gaps
+    program_spans.py         the program's spans placed on the trace
+    weights.py               seeded weights, the `arch` loader, placement
+                             on a mesh
     reference.py, checks.py  the plain reference and the comparison
     control.py               readings of the program, the control and the
                              planted faults on the chip, to set limits from

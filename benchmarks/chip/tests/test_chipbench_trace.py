@@ -131,3 +131,60 @@ def test_decode_roofline_reader_is_the_least_time_over_kernel_time():
     none = trace([op("%fusion.1 = bf16[2] fusion(x)", 0, 1)], spans, mods)
     assert harness.load_reader("decode_kernel_roofline")(
         ctx(none, {"decode_ctx": n * L, "decode_tokens": n})) is None
+
+
+class _Pipe:
+    """Enough of a pipeline for `harness.measure`: each pass of the event
+    loop executes one more publication of 0.25 s into an engine."""
+
+    def __init__(self, executed: int, exec_seconds: float, per_run: int):
+        from types import SimpleNamespace as NS
+        self.stats = {"executed": executed, "exec_seconds": exec_seconds}
+        self.per_run = per_run
+        self.trainer = NS(state=NS(params={}))
+        self.trainer_stage = NS(lag_hist={})
+        self.engines = [NS(tokens_generated=0, prefill_tokens=0,
+                           prompts_rejected=0)]
+        self.actors = [NS(rollouts_lost=0)]
+        self.loop = NS(run=self._run)
+
+    def _run(self, until):
+        self.stats["executed"] += self.per_run
+        self.stats["exec_seconds"] += 0.25 * self.per_run
+
+    def broadcast_stats(self):
+        return dict(self.stats)
+
+
+@pytest.mark.parametrize("per_run", [0, 3])
+def test_install_seconds_per_update_is_a_delta_over_the_window(per_run):
+    from types import SimpleNamespace as NS
+    pipe = _Pipe(executed=5, exec_seconds=10.0, per_run=per_run)
+    rec = NS(loss_tokens=0.0, steps=0, seq_tokens=0, ctx_sum=0, rows=0)
+    d = harness.measure(pipe, rec, NS(done=[]), harness.Counters(), 0.0,
+                        None)
+    got = harness.load_reader("install_s_per_update")(
+        harness.Ctx({}, {}, None, d, {}, 4))
+    if per_run:
+        # 3 publications of 0.25 s in the window, not 10.75 s over 8
+        assert got == pytest.approx(0.25)
+    else:
+        assert got is None
+
+
+def test_train_step_device_time_reduces_each_plane_then_averages():
+    # one optimizer step whose program runs on four chips for 0.1 s each
+    spans = [op(PR.SPAN_PREFIX + "train_step", 1.0, 1.01)]
+    planes = [f"/device:TPU:{i}" for i in range(4)]
+    mods = {p: [op("jit_train_step(1)", 1.0 + 0.001 * i, 1.1 + 0.001 * i)]
+            for i, p in enumerate(planes)}
+    ops = {p: [op("%fusion.1 = bf16[2] fusion(x)", m[0].start, m[0].end)]
+           for p, m in mods.items()}
+    tr = PR.Trace(ops, mods, spans, (0.0, 10.0))
+    assert PR.role_seconds_by_plane(tr, "train_step") == \
+        pytest.approx([0.1] * 4)
+    assert harness.load_reader("train_step_device_ms")(ctx(tr)) == \
+        pytest.approx(100.0)
+    # idle share: the mean of each chip's own
+    assert harness.load_reader("device_idle_share")(ctx(tr)) == \
+        pytest.approx(99.0)

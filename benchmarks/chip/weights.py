@@ -3,18 +3,55 @@ call, in the dtype the configuration serves them in.
 
 The weights belong to the benchmark, not to the program: the reference
 (`reference.py`) makes the same tree again from the same seed, so it takes
-nothing the program produced. `to_program_tree` hands the program the
-layout its model code reads.
+nothing the program produced. The configuration's architecture module,
+`arch/<arch>.py` (`arch`), names the leaves and their shapes and hands
+the program the layout its model code reads. On a cell with a mesh the
+weights are made on the mesh, each leaf split over its largest axis that
+the mesh's size divides (`leaf_sharding`).
 """
 from __future__ import annotations
 
 import functools
+import importlib.util
+import json
+import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+HERE = os.path.dirname(os.path.abspath(__file__))
 DTYPES = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}
+
+
+def load_file(path: str, prefix: str, name: str):
+    """The Python file at `path`, loaded as the module `<prefix>_<name>`."""
+    spec = importlib.util.spec_from_file_location(
+        prefix + "_" + name.replace(".", "_").replace("-", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@functools.lru_cache(maxsize=None)
+def load_arch(name: str):
+    """The module `arch/<name>.py`, loaded by path."""
+    path = os.path.join(HERE, "arch", name + ".py")
+    if not os.path.isfile(path):
+        raise FileNotFoundError(
+            f"no architecture module for arch {name!r}: {path} is missing")
+    return load_file(path, "arch", name)
+
+
+def arch(c: dict):
+    """The architecture module of a configuration file (its `arch` key)."""
+    return load_arch(c["arch"])
+
+
+def frozen(c: dict) -> str:
+    """A configuration as a hashable key (for caches of jitted functions);
+    `json.loads` gives it back."""
+    return json.dumps(c, sort_keys=True)
 
 
 def seed_key(seed: int, stream: int = 0):
@@ -30,48 +67,21 @@ def seed31(seed: int, stream: int = 0) -> int:
     return int(state.generate_state(1)[0] & 0x7FFFFFFF)
 
 
-def shapes(c: dict):
-    """Leaf name -> (shape, kind) of a dense decoder; kind is the init rule:
-    "normal", "out" (normal scaled for the residual), "ones", "zeros"."""
-    d, L, V = c["hidden_size"], c["num_hidden_layers"], c["vocab_size"]
-    H, KV, Dh = (c["num_attention_heads"], c["num_key_value_heads"],
-                 c["head_dim"])
-    F = c["intermediate_size"]
-    out = {
-        "embed": ((V, d), "normal"),
-        "final_norm": ((d,), "ones"),
-        "norm1": ((L, d), "ones"),
-        "wq": ((L, d, H, Dh), "normal"),
-        "wk": ((L, d, KV, Dh), "normal"),
-        "wv": ((L, d, KV, Dh), "normal"),
-        "wo": ((L, H, Dh, d), "out"),
-        "norm2": ((L, d), "ones"),
-        "w_gate": ((L, d, F), "normal"),
-        "w_up": ((L, d, F), "normal"),
-        "w_down": ((L, F, d), "out"),
-    }
-    if not c["tie_word_embeddings"]:
-        out["lm_head"] = ((d, V), "normal")
-    if c.get("value_head"):
-        out["value_head"] = ((d, 1), "zeros")
-    return out
-
-
 def leaf_dtype(c: dict, name: str):
     # the value head is float32 in the program whatever the model dtype
     return jnp.float32 if name == "value_head" else DTYPES[c["dtype"]]
 
 
 @functools.lru_cache(maxsize=None)
-def _maker(spec: tuple):
-    c = dict(spec)
+def _maker(spec: str, mesh=None):
+    c = json.loads(spec)
+    leaves = sorted(arch(c).shapes(c).items())
 
-    @jax.jit
     def make(key):
         std = c["init_std"]
         out_std = std / np.sqrt(2 * c["num_hidden_layers"])
         tree = {}
-        for i, (name, (shape, kind)) in enumerate(sorted(shapes(c).items())):
+        for i, (name, (shape, kind)) in enumerate(leaves):
             dt = leaf_dtype(c, name)
             if kind == "ones":
                 tree[name] = jnp.ones(shape, dt)
@@ -84,55 +94,42 @@ def _maker(spec: tuple):
                 tree[name] = (z * s).astype(dt)
         return tree
 
-    return make
+    if mesh is None:
+        return jax.jit(make)
+    return jax.jit(make, out_shardings={
+        name: leaf_sharding(shape, mesh) for name, (shape, _) in leaves})
 
 
-def _spec(c: dict) -> tuple:
-    keys = ("hidden_size", "num_hidden_layers", "vocab_size",
-            "num_attention_heads", "num_key_value_heads", "head_dim",
-            "intermediate_size", "tie_word_embeddings", "value_head",
-            "dtype", "init_std")
-    return tuple((k, c.get(k)) for k in keys)
-
-
-def make_weights(c: dict, seed: int):
+def make_weights(c: dict, seed: int, mesh=None):
     """The configuration's weights from the seed: one jitted call on the
-    default device."""
-    return _maker(_spec(c))(seed_key(seed, 1))
+    default device, or on `mesh`, split by `leaf_sharding`."""
+    return _maker(frozen(c), mesh)(seed_key(seed, 1))
 
 
-def to_program_tree(w: dict, c: dict) -> dict:
-    """The benchmark's flat naming -> the program's parameter tree."""
-    tree = {
-        "embed": w["embed"],
-        "final_norm": w["final_norm"],
-        "groups": [{
-            "norm1": w["norm1"],
-            "attn": {"wq": w["wq"], "wk": w["wk"], "wv": w["wv"],
-                     "wo": w["wo"]},
-            "norm2": w["norm2"],
-            "ffn": {"gate": w["w_gate"], "up": w["w_up"],
-                    "down": w["w_down"]},
-        }],
-    }
-    if "lm_head" in w:
-        tree["lm_head"] = w["lm_head"]
-    if "value_head" in w:
-        tree["value_head"] = w["value_head"]
-    return tree
+def leaf_sharding(shape, mesh):
+    """A leaf split over its largest axis that the mesh's size divides
+    (the first of equals), over all the mesh's axes; replicated where no
+    axis is divided."""
+    from jax.sharding import NamedSharding, PartitionSpec
+    n = mesh.devices.size
+    fits = [a for a in range(len(shape)) if shape[a] % n == 0]
+    if not fits:
+        return NamedSharding(mesh, PartitionSpec())
+    a = max(fits, key=lambda a: (shape[a], -a))
+    return NamedSharding(mesh, PartitionSpec(
+        *[None] * a, tuple(mesh.axis_names)))
 
 
-def from_program_tree(t: dict) -> dict:
-    """Inverse of `to_program_tree` (for reading the program's state back
-    under the benchmark's names)."""
-    g = t["groups"][0]
-    w = {"embed": t["embed"], "final_norm": t["final_norm"],
-         "norm1": g["norm1"], "norm2": g["norm2"],
-         "wq": g["attn"]["wq"], "wk": g["attn"]["wk"],
-         "wv": g["attn"]["wv"], "wo": g["attn"]["wo"],
-         "w_gate": g["ffn"]["gate"], "w_up": g["ffn"]["up"],
-         "w_down": g["ffn"]["down"]}
-    for k in ("lm_head", "value_head"):
-        if k in t:
-            w[k] = t[k]
-    return w
+def cell_mesh(workload: dict, chips: int):
+    """The mesh a workload file asks for (`"mesh": {"shape": [...],
+    "axes": [...]}`) over the first `chips` devices, or None."""
+    spec = workload.get("mesh")
+    if spec is None:
+        return None
+    from jax.sharding import AxisType
+    shape, axes = tuple(spec["shape"]), tuple(spec["axes"])
+    if int(np.prod(shape)) != chips:
+        raise ValueError(f"mesh {shape} does not cover the cell's {chips} "
+                         "chips")
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=jax.devices()[:chips])
